@@ -37,11 +37,10 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax.numpy as jnp
 
 from repro.core import codec as wire
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.bench import bench_record, metric, write_bench
 from repro.core.quant import compute_quant_params, quantize
 from repro.core.tiling import tile_batch
@@ -112,6 +111,7 @@ def main():
     ap.add_argument("--smoke", action="store_true", help="CI gate, < 60 s")
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args()
+    enable_compile_cache()
     rng = np.random.default_rng(args.seed)
     backends = ("raw", "zlib", "rans", "rans-ctx")
 

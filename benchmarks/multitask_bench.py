@@ -39,11 +39,10 @@ import time
 import jax
 import numpy as np
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from repro.configs.yolo_baf import smoke_config, smoke_data_config
 from repro.core.baf import BaFConvConfig, init_baf_conv
 from repro.data.synthetic import shapes_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import init_cnn
 from repro.obs.bench import bench_record, metric, write_bench
 from repro.pipeline import OperatingPoint
@@ -218,6 +217,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (< 60 s)")
     args = ap.parse_args()
+    enable_compile_cache()
     n_requests = 16 if args.smoke else 48
 
     params, bank, imgs, head_cfg, head_bank = build_system()

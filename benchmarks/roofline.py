@@ -1,9 +1,12 @@
 """Roofline analysis — derive the three terms per (arch × shape) cell from the
 dry-run's compiled artifact (EXPERIMENTS.md §Roofline).
 
-    compute    = HLO_FLOPs_per_device / peak_FLOP/s          (197 TF bf16, v5e)
-    memory     = HLO_bytes_per_device / HBM_bw               (819 GB/s)
-    collective = collective_bytes_per_device / ICI_link_bw   (~50 GB/s/link)
+    compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s
+    memory     = HLO_bytes_per_device / HBM bandwidth
+    collective = collective_bytes_per_device / chip-to-chip bandwidth
+
+Peaks are the v5e entry of repro/launch/chips.py (the dry-run compiles for a
+v5e pod); the collective term uses the chip's whole ICI bandwidth.
 
 Note on "per chips": XLA's cost_analysis runs on the SPMD-*partitioned*
 module, i.e. what ONE chip executes — so dividing by per-chip peaks is the
@@ -31,11 +34,10 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir, "src"))
 
+from repro.launch.chips import chip_peaks  # noqa: E402
 from repro.obs.bench import bench_record, metric, write_bench  # noqa: E402
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # B/s / chip
-ICI_BW = 50e9            # B/s / link
+PEAKS = chip_peaks("TPU v5 lite")      # the dry-run's production mesh
 
 # steps per "unit of work" for MODEL_FLOPS accounting
 _FWD_BWD = {"train": 6.0, "prefill": 2.0, "decode": 2.0, "long": 2.0}
@@ -64,9 +66,9 @@ def analyse(rec: dict, chips: int = 256) -> dict:
     nbytes = rec.get("bytes_scaled") or rec.get("bytes_accessed") or 0.0
     coll = sum((rec.get("collective_bytes_scaled")
                 or rec.get("collective_bytes") or {}).values())
-    t_c = flops / PEAK_FLOPS
-    t_m = nbytes / HBM_BW
-    t_x = coll / ICI_BW
+    t_c = flops / PEAKS.bf16_flops
+    t_m = nbytes / PEAKS.hbm_bytes_per_s
+    t_x = coll / PEAKS.ici_bytes_per_s
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"], rec.get("kind", "train"), chips)
@@ -76,7 +78,7 @@ def analyse(rec: dict, chips: int = 256) -> dict:
         "dominant": dominant,
         "model_flops": mf,
         "useful_ratio": (mf / flops) if flops else 0.0,
-        "roofline_frac": (mf / PEAK_FLOPS) / bound if bound else 0.0,
+        "roofline_frac": (mf / PEAKS.bf16_flops) / bound if bound else 0.0,
         # fraction of the bound step time that is useful model math at peak:
         # = (what an ideal implementation would take) / (this one's bound)
     }

@@ -312,6 +312,8 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated bench names")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

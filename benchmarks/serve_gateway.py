@@ -66,9 +66,8 @@ import time
 import jax
 import numpy as np
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from repro import pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (MetricsRegistry, Tracer, hooks, reconcile_trace,
                        validate_chrome_trace)
 from repro.obs.bench import bench_record, metric, write_bench
@@ -657,6 +656,7 @@ def main():
     ap.add_argument("--trace-only", action="store_true",
                     help="run only part 6 (tracing overhead gate, < 60 s)")
     args = ap.parse_args()
+    enable_compile_cache()
     n = args.requests or (32 if args.smoke else 96)
     c = 8
 

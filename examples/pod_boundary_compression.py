@@ -17,11 +17,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.baf import BaFStreamConfig, init_baf_stream
 from repro.distributed.pipeline import (compressed_pod_transfer,
                                         subset_pod_transfer, wire_bytes)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 B, S, D, C = 4, 64, 256, 64
 
 key = jax.random.PRNGKey(0)

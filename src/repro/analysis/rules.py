@@ -339,8 +339,7 @@ def _check_ra03(ctx: FileContext) -> list:
                 out.append(_v("RA03", ctx, node,
                               f"{name} is the renamed-across-releases "
                               f"compiler-params class; use "
-                              f"kernels.compat.CompilerParams / "
-                              f"tpu_compiler_params(...)"))
+                              f"kernels.compat.CompilerParams"))
     return out
 
 

@@ -15,7 +15,7 @@ The real coder behind the wire codec's ``rans`` / ``rans-ctx`` backends
                        (bit-identical to the per-blob path)
 
 Symbol statistics for static tables are computed on device by the Pallas
-histogram/CDF kernels (repro.kernels.histogram).
+histogram kernel (repro.kernels.histogram).
 """
 from repro.codec.backend import (decode_channels, decode_tensor,
                                  encode_adaptive_tensor, encode_static_tensor)

@@ -1,64 +1,23 @@
-"""jax API compatibility layer (non-Pallas; kernels use kernels/compat.py).
+"""Single import site for the jax APIs that have moved between releases
+(lint rule RA03; Pallas goes through kernels/compat.py).
 
-The repo targets the current jax surface; the container may bake an older
-release. Three renames matter here:
-
-  * ``jax.shard_map`` (new) vs ``jax.experimental.shard_map.shard_map`` (old).
-    The new call spells "manual axes" as ``axis_names={...}`` and replication
-    checking as ``check_vma=``; the old one spells them ``auto=`` (the
-    complement set) and ``check_rep=``. :func:`shard_map` here accepts the
-    NEW spelling and translates down when needed.
-  * ``jax.set_mesh(mesh)`` (new context manager) vs entering the ``Mesh``
-    object itself (old). :func:`set_mesh` returns whichever works.
-  * ``Compiled.cost_analysis()`` returns a dict on new jax but a one-element
-    list of dicts on old jax. :func:`cost_analysis_dict` normalizes.
-
-Everything resolves at import time against the installed jax; call sites
-read as if the new API were present.
+``shard_map`` left ``jax.experimental`` and ``set_mesh`` replaced entering a
+``Mesh``; call sites import both from here, as they do the described (not
+attached) TPU topologies the chip-compile tests build, so the next move is
+absorbed in this one file. The repo targets the installed jax only.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "set_mesh", "cost_analysis_dict"]
+__all__ = ["shard_map", "set_mesh", "tpu_topology"]
+
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
 
 
-if hasattr(jax, "shard_map"):
-    _new_shard_map = jax.shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma: bool = True):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma, **kw)
-else:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma: bool = True):
-        # Old jax's partial-manual mode (``auto=`` complement of axis_names)
-        # trips a fatal XLA partitioner check on 0.4.x
-        # (spmd_partitioner.cc "IsManualSubgroup" assert), so run fully
-        # manual instead: results are identical, the region is just
-        # replicated rather than auto-sharded over the unnamed axes.
-        del axis_names
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-    def set_mesh(mesh):
-        """Old jax: ``Mesh`` is itself the context manager."""
-        return mesh
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+def tpu_topology(name: str):
+    """Describe a TPU topology (e.g. ``"v5e:2x2"``) without a chip attached;
+    its ``devices`` accept compiles, not data. Loads the TPU library."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu", topology_name=name)

@@ -101,12 +101,10 @@ def restore_codes_fused(baf_params, split, sel_idx, codes, mins, maxs, *,
                                sel_idx, z_hat_sel)
     b, h, w, c = codes.shape
     r = h * w
-    block_r = 512 if r % 512 == 0 else r
     cons = consolidate_pallas(
         z_tilde[..., sel_idx].reshape(b, r, c),
         codes.reshape(b, r, c),
-        mins.reshape(b, c), maxs.reshape(b, c),
-        bits, block_r=block_r)
+        mins.reshape(b, c), maxs.reshape(b, c), bits)
     return scatter_consolidated(z_tilde, cons.reshape(b, h, w, c), sel_idx)
 
 

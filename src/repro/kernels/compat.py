@@ -1,61 +1,20 @@
-"""Pallas API compatibility shim.
+"""Single import site for the Pallas modules (lint rule RA03).
 
-The Pallas TPU surface was renamed across jax releases: ``pltpu.CompilerParams``
-(jax >= 0.5 naming, used by current docs) was ``pltpu.TPUCompilerParams``
-before that, and some older releases spell compiler knobs differently again.
-Kernels import the resolved names from here instead of guessing, so the same
-kernel source runs on whatever jax the container bakes in.
+``jax.experimental`` is an unstable namespace — pallas has already moved
+once, and its TPU compiler-params class was renamed — so kernels spell
 
-    from repro.kernels.compat import CompilerParams, tpu_compiler_params
+    from repro.kernels.compat import CompilerParams, pl, pltpu
 
-``tpu_compiler_params(...)`` additionally drops keyword arguments the
-installed class does not accept (e.g. very old jax without
-``dimension_semantics``), degrading to "no hint" rather than crashing —
-the hints are performance metadata, never correctness.
-
-This module is also the one sanctioned import site for the pallas modules
-themselves (lint rule RA03): ``jax.experimental`` is an unstable namespace
-— pallas has already moved once and is slated to graduate out of
-experimental — so kernels spell
-
-    from repro.kernels.compat import pl, pltpu
-
-and a future module move is absorbed here, in one place, instead of in
-every kernel.
+and a future move is absorbed here, in one place, instead of in every
+kernel. The repo targets the installed jax only.
 """
 from __future__ import annotations
-
-import inspect
-from typing import Any
 
 # the import shim boundary: raw jax.experimental is allowed here and in
 # repro/compat.py only (both files are RA03-exempt by config)
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["CompilerParams", "pl", "pltpu", "tpu_compiler_params"]
+__all__ = ["CompilerParams", "pl", "pltpu"]
 
-# Resolve the compiler-params class across the rename. Newest first.
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-elif hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:                                        # pragma: no cover - ancient jax
-    CompilerParams = None
-
-if CompilerParams is not None:
-    _ACCEPTED = frozenset(inspect.signature(CompilerParams).parameters)
-else:                                        # pragma: no cover - ancient jax
-    _ACCEPTED = frozenset()
-
-
-def tpu_compiler_params(**kwargs: Any):
-    """Build a compiler-params object, dropping unsupported keywords.
-
-    Returns None (callers pass ``compiler_params=None``, which pallas_call
-    accepts) when the installed jax exposes no compiler-params class at all.
-    """
-    if CompilerParams is None:               # pragma: no cover - ancient jax
-        return None
-    return CompilerParams(**{k: v for k, v in kwargs.items()
-                             if k in _ACCEPTED})
+CompilerParams = pltpu.CompilerParams

@@ -21,7 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.kernels.compat import pl, pltpu, tpu_compiler_params
+from repro.kernels.compat import CompilerParams, pl, pltpu
 
 NEG_INF = -1e30
 
@@ -115,6 +115,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

@@ -1,4 +1,4 @@
-"""Per-channel symbol histogram + CDF Pallas kernels — codec table stage.
+"""Per-channel symbol histogram Pallas kernel — codec table stage.
 
 The static rANS backend (repro/codec) needs per-channel symbol counts of the
 quantized BaF residual tensor before the host-side coding pass. The codes
@@ -6,17 +6,17 @@ are already on device (the quantize kernel produced them), so the histogram
 should be too: one pass over the codes in VMEM instead of a host bincount
 over a device->host copy.
 
-Kernel 1 (histogram): grid ``(C blocks, R blocks)``; the R axis revisits the
-same output block and accumulates, so arbitrarily long code streams stream
-through a fixed VMEM footprint. Counts are computed as a broadcast
-compare-and-sum against a symbol iota — elementwise VPU work, no MXU.
+Grid ``(C blocks, R blocks)``; the R axis revisits the same output block and
+accumulates, so arbitrarily long code streams stream through a fixed VMEM
+footprint. Counts are computed as a broadcast compare-and-sum against a
+symbol iota — elementwise VPU work, no MXU. The symbols go eight at a time
+(one sublane tile of the (nsym, BC) output), so the compare intermediate is
+(8, BR, BC) whatever the alphabet size; a channel block is the whole C when
+C <= 128 and 128 lanes otherwise, as the TPU tiling rule requires.
 
-Kernel 2 (CDF): one (S, BC) block per channel block; exclusive prefix sum
-along the symbol axis — exactly the cumulative table rANS needs.
-
-Both default to interpret mode on CPU like the other kernels in this
-package; numerics are integer-exact either way (validated against
-``np.bincount`` in tests/test_rans.py).
+Defaults to interpret mode on CPU like the other kernels in this package;
+numerics are integer-exact either way (validated against ``np.bincount`` in
+tests/test_rans.py).
 """
 from __future__ import annotations
 
@@ -27,21 +27,26 @@ import jax.numpy as jnp
 import numpy as np
 from repro.kernels.compat import pl
 
+_SYM_GROUP = 8                  # symbols per step: one (8, BC) output tile
+_LANES = 128
+_BLOCK_R = 512
+
 
 def _hist_kernel(x_ref, counts_ref, *, nsym: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    x = x_ref[...].astype(jnp.int32)                      # (BR, BC)
-    sym = jax.lax.broadcasted_iota(jnp.int32, (nsym, 1, 1), 0)
-    eq = (x[None, :, :] == sym).astype(jnp.int32)         # (S, BR, BC)
-    counts_ref[...] += jnp.sum(eq, axis=1)
+    g = min(nsym, _SYM_GROUP)
 
+    def group(i, carry):
+        s0 = pl.multiple_of(i * g, g)
+        sym = s0 + jax.lax.broadcasted_iota(jnp.int32, (g, 1, 1), 0)
+        eq = (x_ref[...][None, :, :] == sym).astype(jnp.int32)  # (g, BR, BC)
+        counts_ref[pl.ds(s0, g), :] += jnp.sum(eq, axis=1)
+        return carry
 
-def _cdf_kernel(counts_ref, cdf_ref):
-    c = counts_ref[...]
-    cdf_ref[...] = jnp.cumsum(c, axis=0) - c              # exclusive
+    jax.lax.fori_loop(0, nsym // g, group, 0)
 
 
 @functools.lru_cache(maxsize=64)
@@ -58,61 +63,30 @@ def _jitted_hist(nsym: int, br: int, bc: int, rp: int, cp: int,
     return jax.jit(call)
 
 
-def histogram_pallas(codes: jax.Array, nsym: int, *, block_r: int = 256,
-                     block_c: int = 8,
+def histogram_pallas(codes: jax.Array, nsym: int, *,
                      interpret: bool | None = None) -> jax.Array:
     """codes: (R, C) integer array -> counts (nsym, C) int32.
 
+    ``nsym`` is a power of two (an alphabet of ``2**bits`` symbols).
     Out-of-range values (negative or >= nsym) are counted nowhere — callers
     use ``nsym`` itself as the padding sentinel. The pallas_call is jitted
     and cached per shape, so the serving hot path (same tile shape per
     bucket) traces once.
     """
+    if nsym < 2 or nsym & (nsym - 1):
+        raise ValueError(f"nsym must be a power of two >= 2, got {nsym}")
     r, c = codes.shape
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    # the kernel materializes an (nsym, BR, BC) int32 compare — keep that
-    # intermediate within a ~4 MB VMEM budget by shrinking the row block as
-    # the alphabet grows (nsym=4096 at the default blocks would be ~33 MB)
-    bc = min(block_c, max(c, 1))
-    br_cap = max(1, (1 << 20) // (max(nsym, 1) * bc))
-    br = min(block_r, br_cap, max(r, 1))
+    bc = c if c <= _LANES else _LANES
+    br = min(_BLOCK_R, -(-r // 8) * 8)
     pad_r = (-r) % br
     pad_c = (-c) % bc
+    codes = codes.astype(jnp.int32)
     if pad_r or pad_c:
-        codes = jnp.pad(codes.astype(jnp.int32), ((0, pad_r), (0, pad_c)),
-                        constant_values=nsym)
-    rp, cp = r + pad_r, c + pad_c
-    counts = _jitted_hist(nsym, br, bc, rp, cp, interpret)(
-        codes.astype(jnp.int32))
+        codes = jnp.pad(codes, ((0, pad_r), (0, pad_c)), constant_values=nsym)
+    counts = _jitted_hist(nsym, br, bc, r + pad_r, c + pad_c, interpret)(codes)
     return counts[:, :c]
-
-
-@functools.lru_cache(maxsize=64)
-def _jitted_cdf(s: int, bc: int, cp: int, interpret: bool):
-    call = pl.pallas_call(
-        _cdf_kernel,
-        grid=(cp // bc,),
-        in_specs=[pl.BlockSpec((s, bc), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((s, bc), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((s, cp), jnp.int32),
-        interpret=interpret,
-    )
-    return jax.jit(call)
-
-
-def cdf_pallas(counts: jax.Array, *, block_c: int = 8,
-               interpret: bool | None = None) -> jax.Array:
-    """counts: (S, C) -> exclusive CDF (S, C), same dtype widening to i32."""
-    s, c = counts.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    bc = min(block_c, max(c, 1))
-    pad_c = (-c) % bc
-    if pad_c:
-        counts = jnp.pad(counts, ((0, 0), (0, pad_c)))
-    cdf = _jitted_cdf(s, bc, c + pad_c, interpret)(counts.astype(jnp.int32))
-    return cdf[:, :c]
 
 
 def channel_histogram(codes, bits: int, *,
@@ -121,8 +95,7 @@ def channel_histogram(codes, bits: int, *,
 
     codes: (..., C) integers in [0, 2^bits) -> counts (C, S) as a host numpy
     array, ready for table normalization (repro.codec.rans.normalize_freqs
-    runs host-side; the heavy O(R·C·S) reduction stays on device). This is
-    the encoder hot path — the CDF kernel is not run here.
+    runs host-side; the heavy O(R·C·S) reduction stays on device).
     """
     nsym = 1 << bits
     arr = np.asarray(codes)
@@ -135,23 +108,3 @@ def channel_histogram(codes, bits: int, *,
     counts = histogram_pallas(jnp.asarray(flat, jnp.int32), nsym,
                               interpret=interpret)
     return np.asarray(counts).T.astype(np.int64)
-
-
-def channel_histogram_cdf(codes, bits: int, *,
-                          interpret: bool | None = None
-                          ) -> tuple[np.ndarray, np.ndarray]:
-    """Counts plus the exclusive CDF (both (C, S)), both computed on device."""
-    nsym = 1 << bits
-    arr = np.asarray(codes)
-    if arr.ndim == 0:
-        arr = arr.reshape(1, 1)
-    c = arr.shape[-1]
-    flat = arr.reshape(-1, c) if c else arr.reshape(-1, 1)
-    if flat.size == 0 or c == 0:
-        z = np.zeros((c, nsym), np.int64)
-        return z, z.copy()
-    counts = histogram_pallas(jnp.asarray(flat, jnp.int32), nsym,
-                              interpret=interpret)
-    cdf = cdf_pallas(counts, interpret=interpret)
-    return (np.asarray(counts).T.astype(np.int64),
-            np.asarray(cdf).T.astype(np.int64))

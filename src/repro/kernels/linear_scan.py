@@ -25,7 +25,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.kernels.compat import pl, pltpu, tpu_compiler_params
+from repro.kernels.compat import CompilerParams, pl, pltpu
 
 
 def _scan_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, s0_ref,
@@ -125,7 +125,7 @@ def linear_scan_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(q, k, v, log_decay, bonus, initial_state)
     return y, sfinal

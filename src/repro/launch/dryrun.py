@@ -109,8 +109,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, verbose=True,
             for k in ("argument_size_in_bytes", "output_size_in_bytes",
                       "temp_size_in_bytes", "generated_code_size_in_bytes"):
                 rec[k] = getattr(ma, k, None)
-        ca_list = compiled.cost_analysis()
-        ca = ca_list[0] if isinstance(ca_list, (list, tuple)) else ca_list
+        ca = compiled.cost_analysis()
         if ca:
             rec["flops"] = ca.get("flops")
             rec["bytes_accessed"] = ca.get("bytes accessed",

@@ -41,7 +41,8 @@ from repro.compat import shard_map
 from repro.core.split import restore_codes, restore_codes_fused
 from repro.distributed.sharding import params_pspecs
 from repro.launch.hlo_cost import analyze_compiled
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16, make_dev_mesh
+from repro.launch.chips import ChipPeaks, chip_peaks
+from repro.launch.mesh import make_dev_mesh
 from repro.models.cnn import cnn_cloud
 from repro.serve.executor import (CalibratedCostModel, CloudExecutor,
                                   CostModel, _Queue)
@@ -135,12 +136,15 @@ class MeshExecutor(CloudExecutor):
             return cnn_cloud(params, z)
 
         d = self.data_axis
+        # manual over every mesh axis: a Pallas kernel (the fused restore's
+        # consolidation) cannot sit in a region the compiler still
+        # partitions, even over the size-1 model axis
         fn = jax.jit(shard_map(
             body, mesh=self.mesh,
             in_specs=(self._params_specs(plan.spec.baf_params),
                       self._params_specs(plan.spec.params),
                       P(d), P(d), P(d)),
-            out_specs=P(d), axis_names={d}, check_vma=False))
+            out_specs=P(d), check_vma=False))
         self._fns[key] = (plan, fn)
         return fn
 
@@ -163,17 +167,20 @@ class MeshExecutor(CloudExecutor):
 
 
 def seed_cost_from_hlo(plan, sample_shape: tuple, *,
-                       flops_per_s: float = PEAK_FLOPS_BF16,
-                       bytes_per_s: float = HBM_BW) -> CalibratedCostModel:
+                       peaks: ChipPeaks | None = None) -> CalibratedCostModel:
     """Roofline-seeded :class:`CalibratedCostModel` for a plan's cloud body.
 
     Compiles the (serial) restore+forward program for one ``(N, H, W, C)``
     codes shape, runs the trip-count-aware ``launch/hlo_cost`` analysis over
     the compiled HLO, and seeds ``per_item_s`` with the roofline time
-    ``max(flops/flops_per_s, bytes/bytes_per_s) / N``. Measured calibration
-    samples override the seed at ``fit()``; the seed carries fits that would
-    otherwise be degenerate (a single batch size in the samples).
+    ``max(flops/peak flops, bytes/peak HBM bandwidth) / N`` of ``peaks``
+    (default: the first device's entry in launch/chips.py, an error on a
+    device with no published peaks). Measured calibration samples override
+    the seed at ``fit()``; the seed carries fits that would otherwise be
+    degenerate (a single batch size in the samples).
     """
+    if peaks is None:
+        peaks = chip_peaks()
     n = int(sample_shape[0])
     c = int(sample_shape[-1])
     bits, sel = plan.op.bits, plan._sel
@@ -196,5 +203,6 @@ def seed_cost_from_hlo(plan, sample_shape: tuple, *,
     compiled = jax.jit(body).lower(plan.spec.baf_params, plan.spec.params,
                                    codes, mins, maxs).compile()
     est = analyze_compiled(compiled)
-    roof_s = max(est["flops"] / flops_per_s, est["bytes"] / bytes_per_s)
+    roof_s = max(est["flops"] / peaks.bf16_flops,
+                 est["bytes"] / peaks.hbm_bytes_per_s)
     return CalibratedCostModel(seed_per_item_s=roof_s / n)

@@ -121,8 +121,9 @@ def test_grad_compress_all_reduce_multidevice():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim.grad_compress import quantized_pod_mean
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 g = {"w": jnp.linspace(-1, 1, 64).reshape(8, 8)}
 with set_mesh(mesh):
     gp = jax.device_put(g, NamedSharding(mesh, P()))
@@ -149,8 +150,9 @@ import os
 os.environ["DRYRUN_XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_cell
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 for arch, shape in [("qwen2_7b", "train_4k"), ("rwkv6_3b", "decode_32k")]:
     cell = build_cell(arch, shape, mesh, multi_pod=True, smoke=True)
     with set_mesh(mesh):

@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis_dict
 from repro.launch.hlo_cost import analyze_compiled, analyze_hlo_text
 
 
@@ -18,7 +17,7 @@ def test_scan_flops_scaled_by_trip_count():
     r = analyze_compiled(c)
     assert r["flops"] == 12 * 2 * 128 ** 3
     # XLA's own analysis counts the body once — ours must exceed it
-    assert r["flops"] > (cost_analysis_dict(c).get("flops") or 0)
+    assert r["flops"] > (c.cost_analysis().get("flops") or 0)
 
 
 def test_nested_scan():

@@ -43,6 +43,42 @@ def test_quantize_kernel_4d_layout(rng):
     assert qp.mins.shape == (2, 1, 1, 16)   # per-example broadcastable
 
 
+def _f16_probe_values() -> np.ndarray:
+    """Every finite fp16 value, the midpoints between neighbours (ties) and
+    the f32 values either side of each midpoint, plus overflow cases."""
+    h = np.arange(1 << 16, dtype=np.uint16).view(np.float16)
+    h = np.sort(h[np.isfinite(h)])
+    base = h.astype(np.float32)
+    mids = (base[:-1] + base[1:]) / 2
+    return np.concatenate([
+        base, mids, np.nextafter(mids, np.float32(np.inf)),
+        np.nextafter(mids, np.float32(-np.inf)),
+        np.asarray([65519.99, 65520.0, -65520.0, 7e4, -7e4, 1e-9, -1e-9,
+                    np.inf, -np.inf], np.float32)])
+
+
+def test_kernel_f16_rounding_matches_astype():
+    """The quantize kernel rounds side info to fp16 with integer ops on the
+    f32 bits (Mosaic on v5e has no f16); it must equal astype(float16)."""
+    from repro.kernels.quantize import _round_f16
+    x = _f16_probe_values()
+    got = np.asarray(_round_f16(jnp.asarray(x)))
+    with np.errstate(over="ignore"):
+        want = x.astype(np.float16).astype(np.float32)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.signbit(got), np.signbit(want))
+
+
+def test_kernel_f16_next_up_matches_nextafter():
+    from repro.kernels.quantize import _next_f16_up
+    h = np.arange(1 << 16, dtype=np.uint16).view(np.float16)
+    h = h[~np.isnan(h)]
+    got = np.asarray(_next_f16_up(jnp.asarray(h.astype(np.float32))))
+    with np.errstate(over="ignore"):
+        want = np.nextafter(h, np.float16(np.inf)).astype(np.float32)
+    np.testing.assert_array_equal(got, want)
+
+
 # ---------------------------------------------------------------------------
 # consolidate
 # ---------------------------------------------------------------------------

@@ -19,7 +19,8 @@ import pytest
 from repro.configs.yolo_baf import smoke_config, smoke_data_config
 from repro.core.baf import BaFConvConfig, init_baf_conv
 from repro.data.synthetic import shapes_batch_iterator
-from repro.launch.mesh import make_dev_mesh
+from repro.launch.chips import CHIP_PEAKS, chip_peaks
+from repro.launch.mesh import make_dev_mesh, make_mesh
 from repro.models.cnn import init_cnn
 from repro.serve import (CalibratedCostModel, GatewayFederation,
                          LinearCostModel, MeshExecutor, MultiTenantGateway,
@@ -132,7 +133,7 @@ def test_mesh_executor_refuses_unfrozen_calibration():
 
 
 def test_mesh_executor_requires_data_axis():
-    mesh = jax.make_mesh((1, 1), ("pod", "model"))
+    mesh = make_mesh((1, 1), ("pod", "model"))
     with pytest.raises(ValueError, match="data"):
         MeshExecutor(mesh=mesh)
 
@@ -212,7 +213,8 @@ def test_seed_cost_from_hlo_positive(system):
     params, bank = system
     gw = ServingGateway(params, bank, default_op=OP, max_batch=8)
     plan = gw.plan_for(gw.default_op)
-    m = seed_cost_from_hlo(plan, (4, 4, 4, C))
+    m = seed_cost_from_hlo(plan, (4, 4, 4, C),
+                           peaks=chip_peaks("TPU v5 lite"))
     assert isinstance(m, CalibratedCostModel)
     assert not m.frozen
     assert m.seed_per_item_s > 0.0
@@ -220,6 +222,17 @@ def test_seed_cost_from_hlo_positive(system):
     m.observe(8, 0.02)
     m.freeze()
     assert m.per_item_s == m.seed_per_item_s
+
+
+def test_seed_cost_from_hlo_refuses_device_without_peaks(system):
+    params, bank = system
+    gw = ServingGateway(params, bank, default_op=OP, max_batch=8)
+    plan = gw.plan_for(gw.default_op)
+    kind = jax.devices()[0].device_kind
+    if kind in CHIP_PEAKS:
+        pytest.skip(f"{kind!r} has published peaks")
+    with pytest.raises(ValueError, match="no published peaks"):
+        seed_cost_from_hlo(plan, (4, 4, 4, C))
 
 
 # ---------------------------------------------------------------------------

@@ -40,8 +40,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.baf import BaFStreamConfig, init_baf_stream
 from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.distributed.pipeline import compressed_pod_transfer, subset_pod_transfer
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 32), jnp.float32)
 with set_mesh(mesh):
     xs = jax.device_put(x, NamedSharding(mesh, P()))

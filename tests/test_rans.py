@@ -257,24 +257,22 @@ def test_static_close_to_floor_on_skewed_stream(rng):
 
 
 # ---------------------------------------------------------------------------
-# Pallas histogram/CDF kernel vs bincount
+# Pallas histogram kernel vs bincount
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("shape,bits", [((4, 16, 16, 8), 8), ((37, 5), 4),
                                         ((1, 1), 1), ((3, 7, 3), 6)])
 def test_histogram_kernel_matches_bincount(rng, shape, bits):
-    from repro.kernels.histogram import channel_histogram_cdf
+    from repro.kernels.histogram import channel_histogram
     codes = rng.integers(0, 1 << bits, size=shape)
-    counts, cdf = channel_histogram_cdf(codes, bits)
+    counts = channel_histogram(codes, bits)
     c = shape[-1]
     ref = np.stack([np.bincount(codes.reshape(-1, c)[:, i],
                                 minlength=1 << bits) for i in range(c)])
     assert np.array_equal(counts, ref)
-    assert np.array_equal(cdf, np.cumsum(ref, axis=1) - ref)
 
 
 def test_histogram_kernel_empty():
-    from repro.kernels.histogram import channel_histogram_cdf
-    counts, cdf = channel_histogram_cdf(np.empty((0, 4), np.int32), 8)
+    from repro.kernels.histogram import channel_histogram
+    counts = channel_histogram(np.empty((0, 4), np.int32), 8)
     assert counts.shape == (4, 256) and not counts.any()
-    assert cdf.shape == (4, 256) and not cdf.any()
