@@ -92,7 +92,9 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
 
     mat, _ = _as_symbol_matrix(codes, bits)
     n_ch, k = mat.shape
-    counts = channel_histogram(mat.T, bits)       # (K, C): chunk layout
+    # the device round trip inside encode: codes up, counts back
+    with hooks.timed("codec.histogram"):
+        counts = channel_histogram(mat.T, bits)   # (K, C): chunk layout
 
     # scale lanes with the chunk's expected *compressed* size: each lane
     # costs 4 bytes of state on the wire, so a heavily skewed (low-entropy)
@@ -105,11 +107,6 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
                                              0.0)).sum())
     payload_guess = max(1, int(ent_bits / 8) // max(n_ch, 1))
     lanes = max(1, min(STATIC_LANES, k // 32 or 1, payload_guess // 64 or 1))
-    if hooks.enabled():
-        # lane occupancy: interleave width per chunk and symbols each lane
-        # carries — how well the chunk fills the SIMD decode loop
-        hooks.observe("codec_rans_lanes", lanes, mode="static")
-        hooks.observe("codec_rans_lane_occupancy", k / lanes, mode="static")
     prob_bits = min(MAX_PROB_BITS, max(PROB_BITS_STATIC, bits + 2))
     if n_ch == 0 or k == 0:
         chunks = [(0, np.full(lanes, ctx.RANS_L, "<u4"), b"")] * n_ch
@@ -161,10 +158,6 @@ def _encode_adaptive_tensor(codes: np.ndarray, bits: int) -> bytes:
     mat, neighbor = _as_symbol_matrix(codes, bits)
     n_ch, k = mat.shape
     lanes = ctx.plan_lanes(k, neighbor)
-    if hooks.enabled() and k:
-        hooks.observe("codec_rans_lanes", lanes, mode="adaptive")
-        hooks.observe("codec_rans_lane_occupancy", k / lanes,
-                      mode="adaptive")
     chunks = []
     for i in range(n_ch):
         states, words = ctx.encode_ctx(mat[i], bits, lanes, neighbor)

@@ -227,21 +227,13 @@ def _decode_tensor_batch(payloads: "list[bytes]", shape: tuple,
                 key = (h.lanes, h.neighbor_dist)
                 adaptive_groups.setdefault(key, []).append(
                     ((i, j), (states, words)))
-    trace_lanes = hooks.enabled()
+    # all grouped chunks' lanes decode in one vector pass
     for (prob_bits, lanes), entries in static_groups.items():
-        if trace_lanes:
-            # effective interleave width: all grouped chunks' lanes decode
-            # in one vector pass (the whole point of the batched path)
-            hooks.observe("codec_rans_batch_width", len(entries) * lanes,
-                          mode="static")
         rows = _decode_static_group([job for _, job in entries], k,
                                     prob_bits, lanes)
         for (i, j), row in zip((pos for pos, _ in entries), rows):
             mats[i, j] = row
     for (lanes, neighbor), entries in adaptive_groups.items():
-        if trace_lanes:
-            hooks.observe("codec_rans_batch_width", len(entries) * lanes,
-                          mode="adaptive")
         rows = _decode_adaptive_group([job for _, job in entries], k, bits,
                                       lanes, neighbor)
         for (i, j), row in zip((pos for pos, _ in entries), rows):

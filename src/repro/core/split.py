@@ -108,13 +108,23 @@ def restore_codes_fused(baf_params, split, sel_idx, codes, mins, maxs, *,
     return scatter_consolidated(z_tilde, cons.reshape(b, h, w, c), sel_idx)
 
 
+def edge_forward(p, img):
+    """The edge half of the CNN: ``img`` -> split activation (B, H, W, P).
+
+    A named function, not a lambda, so its jitted program reads
+    ``jit_edge_forward`` in a profile, beside ``jit_cnn_cloud`` and the
+    restore's ``jit_restore_codes_fused``."""
+    from repro.models.cnn import cnn_edge      # lazy, as in the callers
+    return cnn_edge(p, img)[1]
+
+
 @lru_cache(maxsize=1)
 def _jitted_cnn_fns():
     # lazy: models.cnn is imported on first use (mirrors the engine's local
     # import), but the jit wrappers are cached so repeated fidelity sweeps
     # (build_rd_table) trace each network once per shape, not once per call
-    from repro.models.cnn import cnn_cloud, cnn_edge
-    return (jax.jit(lambda p, i: cnn_edge(p, i)[1]), jax.jit(cnn_cloud))
+    from repro.models.cnn import cnn_cloud
+    return jax.jit(edge_forward), jax.jit(cnn_cloud)
 
 
 def fidelity_metrics(params, baf_params, sel_idx, img, *, bits: int,
@@ -176,8 +186,8 @@ class SplitInferenceEngine:
     def __init__(self, params, baf_params, sel_idx, *, bits: int = 8,
                  backend: str = "zlib", consolidation: bool = True):
         from repro import pipeline                     # lazy: avoid cycle
-        from repro.models.cnn import cnn_cloud, cnn_edge
-        self._edge_fn = jax.jit(lambda p, img: cnn_edge(p, img)[1])
+        from repro.models.cnn import cnn_cloud
+        self._edge_fn = jax.jit(edge_forward)
         self._cloud_fn = jax.jit(cnn_cloud)
         self.params = params
         self.baf_params = baf_params

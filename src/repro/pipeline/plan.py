@@ -149,10 +149,15 @@ class CompressionPlan:
 
     # -- encode (edge side) -------------------------------------------------
     def _quantize(self, z) -> tuple[np.ndarray, "object"]:
-        """Shared quantize stage -> (codes (B,H,W,C), QuantParams)."""
-        z_sel = z[..., self._sel]
-        qp = compute_quant_params(z_sel, self.op.bits, per_example=True)
-        return np.asarray(quantize(z_sel, qp)), qp
+        """Shared quantize stage -> (codes (B,H,W,C), QuantParams).
+
+        Eager, not jitted: a jitted quantizer moves some codes by one on
+        the TPU. The codes' copy to the host also waits for the edge
+        forward, so the ``pipeline.quantize`` timer holds that wait."""
+        with hooks.timed("pipeline.quantize"):
+            z_sel = z[..., self._sel]
+            qp = compute_quant_params(z_sel, self.op.bits, per_example=True)
+            return np.asarray(quantize(z_sel, qp)), qp
 
     def quantize(self, z) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Quantize the split activation -> (codes, mins, maxs), no coding.
@@ -234,7 +239,6 @@ class CompressionPlan:
             raise ValueError("decode_batch needs at least one blob")
         with hooks.timed("pipeline.decode_batch",
                          backend=self.op.wire_backend):
-            hooks.observe("pipeline_decode_batch_size", len(blobs))
             shape = tuple(blobs[0].shape)
             for blob in blobs:
                 self._check_blob(blob, shape)
@@ -256,7 +260,9 @@ class CompressionPlan:
 
     # -- restore (cloud side, device) ---------------------------------------
     def restore(self, decoded: DecodedBatch):
-        """Dequantize + BaF restore; returns the full-width split activation.
+        """Dequantize + BaF restore; returns the full-width split activation,
+        dispatched and not waited for (the ``pipeline.restore`` timer reads
+        host dispatch time, not device time).
 
         One jitted trace per ``(C, bits, bucket shape)`` — shared process-wide
         across plans and gateways via the module-level jit caches in
@@ -267,8 +273,9 @@ class CompressionPlan:
                 "plan was compiled without model weights (encode/decode "
                 "only); supply params and baf_params in the ModelSpec "
                 "to restore")
-        # timer covers trace/dispatch; device completion belongs to the
-        # caller's compute measurement (the executor's wall_s blocks on it)
+        # the timer covers host dispatch only (and the trace, on a first
+        # call): the device runs on after it returns, and its completion
+        # belongs to the caller, which blocks on it (gateway.cloud)
         with hooks.timed("pipeline.restore", fused=self.fused):
             split = self.spec.params["split"]
             codes = jnp.asarray(decoded.codes)

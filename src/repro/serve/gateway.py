@@ -52,6 +52,7 @@ import numpy as np
 
 from repro import pipeline
 from repro.core.split import SplitStats, _jitted_cnn_fns, activation_stats
+from repro.obs import hooks
 from repro.pipeline import Capabilities, ModelSpec, OperatingPoint, negotiate
 from repro.serve.batcher import EncodedRequest, MicroBatch, MicroBatcher
 from repro.serve.channel import ChannelConfig, SimulatedChannel, Transmission
@@ -199,7 +200,8 @@ class ServingGateway:
         """
         op = self._pick_op(t_submit)
         plan = self.plan_for(op)
-        z = self._edge_fn(self.params, img)
+        with hooks.timed("gateway.edge"):
+            z = self._edge_fn(self.params, img)
         blob = plan.encode(z)
         if self.channel is not None:
             tx = self.channel.transmit_bytes(blob.data, t_submit)
@@ -217,16 +219,19 @@ class ServingGateway:
         request on arrival.
         """
         plan = self.plan_for(batch.key.op)
-        # repro: allow[RA01] -- warm-timing helper: measures real compute
-        # wall for MeasuredCost/CalibratedCostModel; feeds telemetry, never
-        # the virtual clock
-        t0 = time.perf_counter()
-        decoded = plan.decode_batch([r.blob for r in batch.requests])
-        z_tilde = plan.restore(decoded.pad_to(batch.padded_size))
-        logits = self._cloud_fn(self.params, z_tilde)
-        logits = np.asarray(jax.block_until_ready(logits))
-        # repro: allow[RA01] -- warm-timing helper (see t0 above)
-        return logits, time.perf_counter() - t0
+        with hooks.timed("gateway.batch", padded=batch.padded_size):
+            # repro: allow[RA01] -- warm-timing helper: measures real compute
+            # wall for MeasuredCost/CalibratedCostModel; feeds telemetry,
+            # never the virtual clock
+            t0 = time.perf_counter()
+            decoded = plan.decode_batch([r.blob for r in batch.requests])
+            z_tilde = plan.restore(decoded.pad_to(batch.padded_size))
+            # the host waiting on restore and the cloud forward together
+            with hooks.timed("gateway.cloud"):
+                logits = self._cloud_fn(self.params, z_tilde)
+                logits = np.asarray(jax.block_until_ready(logits))
+            # repro: allow[RA01] -- warm-timing helper (see t0 above)
+            return logits, time.perf_counter() - t0
 
     def _run_batch_mesh(self, batch: MicroBatch) -> tuple[np.ndarray, float]:
         """Batched decode on the host, restore + cloud forward on the mesh.

@@ -210,6 +210,67 @@ def test_hooks_active_uninstalls_on_exception():
     assert not hooks.enabled()
 
 
+class _RecordingAnnotate:
+    """A fake ``annotate`` factory: logs each span's enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **labels):
+        log = self.log
+
+        class _Span:
+            def __enter__(self):
+                log.append(("enter", name, labels))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, exc[0]))
+                return False
+        return _Span()
+
+
+def test_hooks_annotate_wraps_each_timed_stage():
+    m, ann = MetricsRegistry(), _RecordingAnnotate()
+    with hooks.active(m, annotate=ann):
+        with hooks.timed("outer", backend="rans"):
+            with hooks.timed("inner", padded=8):
+                assert ann.log[-1] == ("enter", "inner", {"padded": 8})
+    assert ann.log == [("enter", "outer", {"backend": "rans"}),
+                       ("enter", "inner", {"padded": 8}),
+                       ("exit", "inner", None),
+                       ("exit", "outer", None)]
+    # the span wraps the same interval the histogram times
+    assert m.get("stage_seconds", stage="outer", backend="rans").count == 1
+    assert m.get("stage_seconds", stage="inner", padded=8).count == 1
+
+
+def test_hooks_annotate_exits_when_the_body_raises():
+    m, ann = MetricsRegistry(), _RecordingAnnotate()
+    with hooks.active(m, annotate=ann):
+        with pytest.raises(ValueError):
+            with hooks.timed("stage_x"):
+                raise ValueError("boom")
+    assert ann.log == [("enter", "stage_x", {}),
+                       ("exit", "stage_x", ValueError)]
+    assert m.get("stage_seconds", stage="stage_x").count == 1
+
+
+def test_hooks_annotate_unused_when_not_installed():
+    ann = _RecordingAnnotate()
+    with hooks.active(MetricsRegistry(), annotate=ann):
+        pass
+    assert hooks.timed("a") is hooks._NULL       # uninstall drops annotate
+    with hooks.timed("a", backend="rans"):
+        pass
+    assert ann.log == []
+    # a registry without annotate times, and writes no span
+    m = MetricsRegistry()
+    with hooks.active(m):
+        with hooks.timed("b"):
+            pass
+    assert ann.log == [] and m.get("stage_seconds", stage="b").count == 1
+
+
 # ---------------------------------------------------------------------------
 # Telemetry on the registry
 # ---------------------------------------------------------------------------

@@ -166,6 +166,40 @@ def test_single_tenant_serve_traces(tiny_system):
     assert gw.metrics.get("executor_utilization") is not None
 
 
+def test_serve_records_every_stage_of_the_request_path(tiny_system):
+    params, bank, imgs = tiny_system
+    gw = ServingGateway(params, bank,
+                        default_op=OperatingPoint(c=8, bits=8,
+                                                  backend="rans"),
+                        max_batch=4)
+    m, spans = MetricsRegistry(), []
+
+    class _Span:
+        def __init__(self, name, **labels):
+            self.name = name
+
+        def __enter__(self):
+            spans.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    with hooks.active(m, annotate=_Span):
+        gw.serve(imgs[:5])
+    stages = {labels["stage"] for name, labels, _ in m.collect()
+              if name == "stage_seconds"}
+    path = {"gateway.edge", "pipeline.quantize", "pipeline.encode",
+            "codec.histogram", "gateway.batch", "pipeline.decode_batch",
+            "pipeline.restore", "gateway.cloud"}
+    assert path <= stages
+    assert path <= set(spans)
+    # one edge, quantize and histogram per request; one cloud per batch
+    assert spans.count("gateway.edge") == 5
+    assert spans.count("pipeline.quantize") == 5
+    assert spans.count("codec.histogram") == 5
+    assert spans.count("gateway.cloud") == spans.count("gateway.batch") >= 2
+
+
 def test_reconcile_requires_span_per_record(tiny_system):
     params, bank, imgs = tiny_system
     gw = _make_mt(params, bank, tracer=Tracer(), metrics=None)
