@@ -28,7 +28,7 @@ from repro.codec import container as box
 from repro.codec import context as ctx
 from repro.obs import hooks
 from repro.codec.rans import (MAX_PROB_BITS, CorruptStream, RansTable,
-                              encode_static, normalize_freqs)
+                              encode_static_rows, normalize_freqs)
 
 MAX_BITS = 12                # slot tables are 2^prob_bits; keep them sane
 STATIC_LANES = 32
@@ -116,10 +116,10 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
             lanes=lanes, neighbor_dist=0, tables=tables, chunks=chunks)
 
     def build(tables: list[RansTable]):
-        chunks = []
-        for i in range(n_ch):
-            states, words = encode_static(mat[i], tables[i], lanes)
-            chunks.append((k, states, words))
+        # every chunk of the container in one interleaved loop
+        hooks.observe("codec_encode_rows", n_ch)
+        states, words = encode_static_rows(mat, tables, lanes)
+        chunks = [(k, states[i], words[i]) for i in range(n_ch)]
         return box.pack_container(
             mode=box.MODE_STATIC, bits=bits, prob_bits=prob_bits,
             lanes=lanes, neighbor_dist=0,

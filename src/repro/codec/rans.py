@@ -12,12 +12,18 @@ Construction (all little-endian):
     ``prob_bits <= 16``, so at most ONE renormalization per symbol — the
     per-step emit is a single masked operation, no data-dependent loops.
   * lane l owns symbols l, l+N, l+2N, …; encoding walks the symbols in
-    reverse, emitting each step's renorm words in reverse lane order and
-    reversing the whole word array at the end, so the decoder (walking
-    forward) reads words in increasing lane order with a single pointer.
+    reverse and lays its renorm words out forward, step by step and in
+    increasing lane order within a step, so the decoder (walking forward)
+    reads them with a single pointer.
   * the encoder takes *per-symbol* (freq, cumfreq) arrays — one static table
     (``RansTable``) or a context model (repro.codec.context) both reduce to
     a gather before the coding loop, so the loop itself is model-agnostic.
+  * the encoder codes M streams of one length at once (``rans_encode_rows``):
+    M stacked rows x N lanes of state advance through one reverse loop of
+    K / N steps, and each row's renorm words are picked out afterwards, so a
+    container's C chunks cost one Python-level loop, not C. Row m's states
+    and words are exactly what coding stream m alone gives; one stream is
+    the M = 1 case.
   * decoding a full stream must return every lane to the initial state L;
     ``rans_decode`` checks this, which catches most payload corruption that
     happens to keep slots in range.
@@ -134,37 +140,66 @@ def pad_to_lanes(symbols: np.ndarray, lanes: int,
         [symbols, np.full(rem, pad_value, dtype=symbols.dtype)])
 
 
-def rans_encode(freqs: np.ndarray, cums: np.ndarray, prob_bits: int,
-                lanes: int) -> tuple[np.ndarray, bytes]:
-    """Encode a symbol stream given its per-symbol (freq, cumfreq) gathers.
+def rans_encode_rows(freqs: np.ndarray, cums: np.ndarray, prob_bits: int,
+                     lanes: int) -> tuple[np.ndarray, list[bytes]]:
+    """Encode M symbol streams at once given their (freq, cumfreq) gathers.
 
-    freqs/cums: (K,) with K a multiple of ``lanes`` (callers pad, see
-    :func:`pad_to_lanes`); entry i belongs to symbol i of the stream.
-    Returns ``(final lane states (lanes,) uint32, word stream bytes)``.
+    freqs/cums: (M, K) with K a multiple of ``lanes`` (callers pad, see
+    :func:`pad_to_lanes`); row m holds stream m, entry (m, i) its symbol i.
+    One reverse interleave loop of K / lanes steps codes all M rows side by
+    side, M x lanes states wide; each row keeps its own lane states and its
+    own word stream. Returns ``(final lane states (M, lanes) uint32, [word
+    stream bytes of row m])``, row m exactly what coding it alone gives.
     """
-    k = freqs.size
-    if k % lanes or lanes < 1:
+    f = np.asarray(freqs)
+    c = np.asarray(cums)
+    if f.ndim != 2 or c.shape != f.shape:
+        raise ValueError(f"freqs {f.shape} and cums {c.shape} must be one "
+                         f"(M, K) shape")
+    m, k = f.shape
+    if lanes < 1 or k % lanes:
         raise ValueError(f"{k} symbols do not fill {lanes} lanes")
+    steps = k // lanes
     shift = _U64(32 - prob_bits)
     pb = _U64(prob_bits)
-    f = np.ascontiguousarray(freqs, _U64).reshape(-1, lanes)
-    c = np.ascontiguousarray(cums, _U64).reshape(-1, lanes)
-    x = np.full(lanes, RANS_L, _U64)
-    chunks: list[np.ndarray] = []
-    for t in range(f.shape[0] - 1, -1, -1):
-        ft, ct = f[t], c[t]
-        need = x >= (ft << shift)
-        if need.any():
-            # reverse lane order: the final global reversal flips it back,
-            # so the decoder reads renorm words in increasing lane order
-            chunks.append((x[need] & _U64(0xFFFF)).astype("<u2")[::-1])
-            x = np.where(need, x >> _U64(WORD_BITS), x)
-        x = ((x // ft) << pb) + (x % ft) + ct
-    if chunks:
-        words = np.concatenate(chunks)[::-1]
-    else:
-        words = np.empty(0, "<u2")
-    return x.astype("<u4"), words.tobytes()
+    # step-major so each step reads one contiguous (M, lanes) block
+    f = np.ascontiguousarray(f.reshape(m, steps, lanes).transpose(1, 0, 2),
+                             _U64)
+    c = np.ascontiguousarray(c.reshape(m, steps, lanes).transpose(1, 0, 2),
+                             _U64)
+    x_max = f << shift
+    x = np.full((m, lanes), RANS_L, _U64)
+    # each step's renorm mask and the low word of every state; the words
+    # are picked out per row once the loop is done
+    need = np.empty((steps, m, lanes), bool)
+    low = np.empty((steps, m, lanes), "<u2")
+    for t in range(steps - 1, -1, -1):
+        nt = np.greater_equal(x, x_max[t], out=need[t])
+        low[t] = x                                # keeps the low 16 bits
+        x = np.where(nt, x >> _U64(WORD_BITS), x)
+        q, r = np.divmod(x, f[t])
+        x = (q << pb) + r + c[t]
+    # row-major over (M, steps, lanes) is the decoder's order: step by step
+    # forward, lanes increasing within a step, one row after another
+    sel = need.transpose(1, 0, 2)
+    words = low.transpose(1, 0, 2)[sel]
+    ends = np.cumsum(need.sum(axis=(0, 2)))
+    rows = np.split(words, ends[:-1]) if m else []
+    return x.astype("<u4"), [w.tobytes() for w in rows]
+
+
+def rans_encode(freqs: np.ndarray, cums: np.ndarray, prob_bits: int,
+                lanes: int) -> tuple[np.ndarray, bytes]:
+    """Encode one symbol stream: the M = 1 case of :func:`rans_encode_rows`.
+
+    freqs/cums: (K,) with K a multiple of ``lanes``; entry i belongs to
+    symbol i of the stream. Returns ``(final lane states (lanes,) uint32,
+    word stream bytes)``.
+    """
+    states, words = rans_encode_rows(np.reshape(freqs, (1, -1)),
+                                     np.reshape(cums, (1, -1)),
+                                     prob_bits, lanes)
+    return states[0], words[0]
 
 
 def rans_decode(states: np.ndarray, words: bytes, count: int,
@@ -211,18 +246,33 @@ def rans_decode(states: np.ndarray, words: bytes, count: int,
     return out.reshape(-1)[:count]
 
 
+def encode_static_rows(symbols: np.ndarray, tables: list[RansTable],
+                       lanes: int) -> tuple[np.ndarray, list[bytes]]:
+    """Static-table coder for M streams of one length: pad, gather (f, c),
+    and code all rows in one :func:`rans_encode_rows` loop.
+
+    symbols: (M, K); row m is coded with ``tables[m]`` (all of one
+    ``prob_bits``). Padding uses each row's most probable symbol (cheapest
+    per pad symbol); the decoder truncates by count, so only the wire cost
+    is affected. Returns ``(lane states (M, lanes), [word bytes of row m])``.
+    """
+    symbols = np.asarray(symbols, np.uint32)
+    freqs = np.stack([t.freqs for t in tables])
+    cums = np.stack([t.cum for t in tables])
+    pad = (-symbols.shape[1]) % lanes
+    if pad:
+        fill = np.argmax(freqs, axis=1).astype(np.uint32)
+        symbols = np.concatenate(
+            [symbols, np.repeat(fill[:, None], pad, axis=1)], axis=1)
+    return rans_encode_rows(np.take_along_axis(freqs, symbols, axis=1),
+                            np.take_along_axis(cums, symbols, axis=1),
+                            tables[0].prob_bits, lanes)
+
+
 def encode_static(symbols: np.ndarray, table: RansTable,
                   lanes: int) -> tuple[np.ndarray, bytes]:
-    """Static-table convenience wrapper: pad, gather (f, c), run the coder.
-
-    Padding uses the table's most probable symbol (cheapest per pad symbol);
-    the decoder truncates by count, so only the wire cost is affected.
-    """
-    symbols = np.asarray(symbols).reshape(-1)
-    if symbols.size == 0:
-        return np.full(lanes, RANS_L, "<u4"), b""
-    pad_value = int(np.argmax(table.freqs))
-    padded = pad_to_lanes(symbols.astype(np.uint32), lanes, pad_value)
-    f = table.freqs[padded]
-    c = table.cum[padded]
-    return rans_encode(f, c, table.prob_bits, lanes)
+    """One stream under one static table: the M = 1 case of
+    :func:`encode_static_rows`."""
+    states, words = encode_static_rows(np.reshape(symbols, (1, -1)),
+                                       [table], lanes)
+    return states[0], words[0]

@@ -3,6 +3,8 @@
 Round-trip properties run under hypothesis when installed (via the
 hypothesis_compat shim) and as seeded spot checks otherwise.
 """
+import hashlib
+
 import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
@@ -12,7 +14,11 @@ from repro.codec import (CorruptStream, RansContainer, RansTable,
                          encode_adaptive_tensor, encode_ctx,
                          encode_static_tensor, normalize_freqs, plan_lanes,
                          rans_decode)
-from repro.codec.rans import RANS_L, encode_static
+from repro.codec import container as box
+from repro.codec.batch import decode_tensor_batch
+from repro.codec.rans import RANS_L, encode_static, rans_encode_rows
+from repro.obs import hooks
+from repro.obs.metrics import MetricsRegistry
 from repro.core import codec as wire
 from repro.core.quant import QuantParams
 
@@ -117,6 +123,173 @@ def test_odd_bit_widths_both_modes(rng, bits):
     for fn in (encode_static_tensor, encode_adaptive_tensor):
         assert np.array_equal(
             decode_tensor(fn(codes, bits), codes.shape, bits), codes)
+
+
+# ---------------------------------------------------------------------------
+# the row-stacked coder against a scalar reference
+# ---------------------------------------------------------------------------
+
+def _scalar_rans_encode(syms, freqs, cums, prob_bits, lanes):
+    """ryg_rans rules, one symbol at a time: 32-bit states in [2^16, 2^32),
+    16-bit renorm words, symbol i on lane i % lanes. Symbols are coded last
+    to first, each pushing its renorm word; the decoder pops them, so the
+    stream is the pushes reversed."""
+    x = [1 << 16] * lanes
+    pushed = []
+    for i in range(len(syms) - 1, -1, -1):
+        f, c, lane = int(freqs[syms[i]]), int(cums[syms[i]]), i % lanes
+        if x[lane] >= f << (32 - prob_bits):
+            pushed.append(x[lane] & 0xFFFF)
+            x[lane] >>= 16
+        x[lane] = ((x[lane] // f) << prob_bits) + x[lane] % f + c
+    return (np.array(x, "<u4"),
+            np.array(pushed[::-1], "<u2").tobytes())
+
+
+_ROW_BITS = 6                                   # 64-symbol alphabet
+
+
+def _row_table(kind, prob_bits, rng):
+    nsym = 1 << _ROW_BITS
+    if kind == "uniform":
+        counts = np.ones(nsym)
+    elif kind == "skewed":
+        counts = np.floor(1e6 * 0.35 ** np.arange(nsym))
+    else:                                       # one symbol holds all mass
+        counts = np.zeros(nsym)
+        counts[rng.integers(nsym)] = 1
+    return RansTable.from_counts(counts, prob_bits)
+
+
+def _check_rows(syms, tables, prob_bits, lanes):
+    """Encode ``syms`` (M, K) stacked and prove every row against the
+    scalar reference, ``rans_decode`` and the batched container decoder."""
+    m, k = syms.shape
+    pad = (-k) % lanes
+    fill = [int(np.argmax(t.freqs)) for t in tables]
+    padded = np.concatenate(
+        [syms, np.repeat(np.array(fill, np.uint32)[:, None], pad, 1)], 1)
+    freqs = np.stack([t.freqs for t in tables])
+    cums = np.stack([t.cum for t in tables])
+    states, words = rans_encode_rows(np.take_along_axis(freqs, padded, 1),
+                                     np.take_along_axis(cums, padded, 1),
+                                     prob_bits, lanes)
+    assert states.shape == (m, lanes) and len(words) == m
+    for i in range(m):
+        ref_states, ref_words = _scalar_rans_encode(
+            padded[i], tables[i].freqs, tables[i].cum, prob_bits, lanes)
+        assert np.array_equal(states[i], ref_states), i
+        assert words[i] == ref_words, i
+        assert np.array_equal(
+            rans_decode(states[i], words[i], k, tables[i], lanes), syms[i])
+    blob = box.pack_container(
+        mode=box.MODE_STATIC, bits=_ROW_BITS, prob_bits=prob_bits,
+        lanes=lanes, neighbor_dist=0, tables=[t.freqs for t in tables],
+        chunks=[(k, states[i], words[i]) for i in range(m)])
+    # shape (K, M): chunk i is column i, so row i comes back as column i
+    out = decode_tensor_batch([blob, blob], (k, m), _ROW_BITS)
+    assert np.array_equal(out[0].reshape(k, m).T, syms)
+    assert np.array_equal(out[1], out[0])
+    return words
+
+
+@pytest.mark.parametrize("table_kind", ["uniform", "skewed", "single"])
+@pytest.mark.parametrize("prob_bits", [9, 14, 15])
+@pytest.mark.parametrize("k_kind", ["padded", "one_step"])
+@pytest.mark.parametrize("lanes", [1, 3, 32])
+@pytest.mark.parametrize("m", [1, 2, 8, 130])
+def test_rans_encode_rows_matches_scalar_reference(m, lanes, k_kind,
+                                                   prob_bits, table_kind):
+    r = np.random.default_rng([m, lanes, prob_bits, len(table_kind)])
+    # "padded": K leaves the last step part-filled (lanes > 1); "one_step":
+    # K = lanes, a single interleave step
+    k = lanes if k_kind == "one_step" else 2 * lanes + 1 + (lanes > 1)
+    tables = [_row_table(table_kind, prob_bits, r) for _ in range(m)]
+    syms = np.stack([
+        r.choice(1 << _ROW_BITS, size=k,
+                 p=t.freqs / t.freqs.sum()).astype(np.uint32)
+        for t in tables])
+    _check_rows(syms, tables, prob_bits, lanes)
+
+
+def test_rans_encode_rows_silent_row_beside_busy_rows():
+    # a few steps of the dominant symbol never push a state past x_max, so
+    # row 1 emits no renorm word while its uniform neighbours emit many
+    r = np.random.default_rng(14)
+    prob_bits, lanes, k = 14, 3, 7
+    single = _row_table("single", prob_bits, r)
+    uniform = _row_table("uniform", prob_bits, r)
+    syms = np.stack([
+        r.integers(0, 1 << _ROW_BITS, size=k),
+        np.full(k, int(np.argmax(single.freqs))),
+        r.integers(0, 1 << _ROW_BITS, size=k)]).astype(np.uint32)
+    words = _check_rows(syms, [uniform, single, uniform], prob_bits, lanes)
+    assert words[1] == b"" and words[0] and words[2]
+
+
+def test_rans_encode_rows_rejects_partial_steps():
+    with pytest.raises(ValueError, match="do not fill"):
+        rans_encode_rows(np.ones((2, 5)), np.zeros((2, 5)), 9, 3)
+    with pytest.raises(ValueError, match="one"):
+        rans_encode_rows(np.ones((2, 6)), np.zeros((2, 3)), 9, 3)
+
+
+# ---------------------------------------------------------------------------
+# static container bytes: pinned, and the stacked loop's counter
+# ---------------------------------------------------------------------------
+
+def _laplace_codes(seed, scales):
+    """(1, 64, 64, C) 8-bit codes around 128, channel c of width scales[c]:
+    the paper's split tensor at the least and most compressed points."""
+    r = np.random.default_rng(seed)
+    z = r.laplace(0.0, 1.0, size=(1, 64, 64, len(scales))) * scales
+    return np.clip(np.round(128 + z), 0, 255).astype(np.uint32)
+
+
+# sha256 of the containers as the per-channel coding loop wrote them; the
+# stacked loop must reproduce every byte (so wire sizes cannot move)
+_GOLDEN = {
+    "c128_per_channel": (
+        1401, np.geomspace(0.5, 40, 128), False,
+        "5611bee3f1aacd731bcf676ff9fb12f293760d06805b617b9c9ca75cbbd11261"),
+    "c128_pooled": (
+        1403, np.full(128, 6.0), True,
+        "5fbdd670c9b935248346a2ed27ef76fbdc8319f30f3d7fab01a4c26d452f014e"),
+    "c8_per_channel": (
+        1402, np.geomspace(1, 30, 8), False,
+        "8c1381dba0ee770c1d44c353fed9ec1eda53a4e775466b64bd4adf375fc6f078"),
+    "c8_pooled": (
+        1404, np.full(8, 6.0), True,
+        "bbab62a4ccb4103dac81af9061f5fdb8c1df219a19040168e941615db5678293"),
+    # a narrow field takes 15 lanes, so every chunk ends in a padded step
+    "c8_pooled_15_lanes": (
+        1404, np.full(8, 0.7), True,
+        "ba4c208f6d336805e1e86b753cbd8e77f49b06683dd4d6838e9ecb04fec36142"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GOLDEN))
+def test_static_container_bytes_are_pinned(case):
+    seed, scales, pooled, digest = _GOLDEN[case]
+    codes = _laplace_codes(seed, scales)
+    blob = encode_static_tensor(codes, 8)
+    cont = RansContainer.parse(blob)
+    tabs = [cont.chunk_table(j) for j in range(cont.header.n_chunks)]
+    assert all(np.array_equal(t, tabs[0]) for t in tabs) == pooled
+    assert hashlib.sha256(blob).hexdigest() == digest
+    assert np.array_equal(decode_tensor(blob, codes.shape, 8), codes)
+
+
+@pytest.mark.parametrize("c", [1, 8, 128])
+def test_static_encode_observes_rows_once(rng, c):
+    codes = rng.integers(0, 256, size=(1, 16, 16, c)).astype(np.uint32)
+    m = MetricsRegistry()
+    with hooks.active(m):
+        blob = encode_static_tensor(codes, 8)
+    rows = m.get("codec_encode_rows")
+    assert rows is not None and rows.count == 1 and rows.total == c
+    assert encode_static_tensor(codes, 8) == blob    # nothing installed
+    assert not hooks.enabled() and m.get("codec_encode_rows").count == 1
 
 
 def test_rans_rejects_out_of_range_codes(rng):
