@@ -15,10 +15,16 @@ total at vector width ``M * lanes`` — each chunk still consumes its own
 word stream through a per-row pointer, so outputs are bit-identical to the
 per-blob decoder (the batched pipeline's hard invariant).
 
-Static-table chunks and adaptive-context chunks batch separately; within
-the adaptive group the per-chunk adaptation state (context counts, tables)
+Static-table chunks and adaptive-context chunks batch separately. A static
+group builds one slot -> symbol table per row, once, in a single vectorized
+``np.repeat`` (``M x 2^prob_bits`` entries, one byte each up to 8 bits and
+two above: 16 MiB for 1,024 chunks at ``prob_bits`` 14, freed when the group
+returns), so each step decodes every lane with one gather. Within the
+adaptive group the per-chunk adaptation state (context counts, tables)
 carries a leading batch axis and refreshes on the same schedule as the
-scalar model, so encoder/decoder symmetry is preserved by construction.
+scalar model, so encoder/decoder symmetry is preserved by construction; its
+tables change at every refresh, so it looks slots up by comparing with the
+cumulative frequencies instead (:func:`_slot_lookup`).
 
 All integrity checks of the scalar path run here too: container/chunk CRCs
 (via ``RansContainer.chunk_parts``), word-stream exhaustion, and the
@@ -31,7 +37,7 @@ import numpy as np
 from repro.codec import container as box
 from repro.codec import context as ctx
 from repro.codec.backend import _chunk_layout
-from repro.codec.rans import RANS_L, WORD_BITS, CorruptStream, RansTable
+from repro.codec.rans import MAX_PROB_BITS, RANS_L, WORD_BITS, CorruptStream
 from repro.obs import hooks
 
 _U64 = np.uint64
@@ -78,16 +84,52 @@ def _finish_checks(x, ptr, wlen):
 
 
 def _slot_lookup(slot: np.ndarray, cums_rows: np.ndarray) -> np.ndarray:
-    """Slot -> symbol without materializing 2^prob_bits lookup tables.
+    """Slot -> symbol by comparing with each row's cumulative frequencies.
 
     ``cums_rows`` is each row's exclusive cumulative-frequency array; the
-    decoded symbol is the last one whose cum <= slot. The scalar coder
-    answers this with a ``(1 << prob_bits)``-entry table — thousands of
-    entries per symbol decoded on small tiles, the dominant cost of the
-    per-blob loop. The broadcast count over the S-symbol alphabet is
-    bit-identical and O(S) per lane instead of O(2^prob_bits) per table."""
+    decoded symbol is the last one whose cum <= slot. Only the adaptive
+    group uses it: its tables are rebuilt at every refresh, so a slot table
+    would be rebuilt as often, while the compare is O(S) per lane and needs
+    no state. Static groups gather from slot tables built once
+    (:func:`_slot_tables`)."""
     return (np.sum(slot[..., None] >= cums_rows, axis=-1) - 1).astype(
         np.int64)
+
+
+def _static_tables(tables: "list[np.ndarray]",
+                   prob_bits: int) -> np.ndarray:
+    """Stack M static frequency tables -> (M, S) uint64, with the checks
+    :class:`RansTable` makes on one, raising :class:`CorruptStream` that
+    names the first bad batch row."""
+    freqs = np.stack([np.asarray(t) for t in tables]).astype(_U64)
+    if prob_bits > MAX_PROB_BITS:
+        # no encoder writes it, and its slot tables would be 2^prob_bits
+        # entries per row
+        raise CorruptStream(
+            f"container prob_bits {prob_bits} exceeds {MAX_PROB_BITS}")
+    sums = freqs.sum(axis=1)
+    off = sums != _U64(1 << prob_bits)
+    if off.any():
+        bad = int(np.argmax(off))
+        raise CorruptStream(
+            f"frequency table of batch row {bad} sums to {int(sums[bad])}, "
+            f"expected {1 << prob_bits}")
+    zero = (freqs == 0).any(axis=1)
+    if zero.any():
+        raise CorruptStream(
+            f"frequency table of batch row {int(np.argmax(zero))} has "
+            f"zero-frequency symbols")
+    return freqs
+
+
+def _slot_tables(freqs: np.ndarray, prob_bits: int) -> np.ndarray:
+    """(M, S) checked frequency tables -> (M, 2^prob_bits) slot -> symbol
+    tables, row m what ``RansTable.slot_symbols`` gives for table m, built
+    in one ``np.repeat``."""
+    m, nsym = freqs.shape
+    syms = np.arange(nsym, dtype=np.uint8 if nsym <= 256 else np.uint16)
+    reps = freqs.ravel().astype(np.int64)
+    return np.repeat(np.tile(syms, m), reps).reshape(m, 1 << prob_bits)
 
 
 def _decode_static_group(jobs, count: int, prob_bits: int,
@@ -95,10 +137,10 @@ def _decode_static_group(jobs, count: int, prob_bits: int,
     """jobs: [(states, words bytes, freq table (S,) array)] -> (M, count)."""
     m = len(jobs)
     steps = -(-count // lanes)
-    tables = [RansTable(freqs=np.asarray(t, np.uint32), prob_bits=prob_bits)
-              for _, _, t in jobs]
-    freqs = np.stack([t.freqs for t in tables]).astype(_U64)
-    cums = np.stack([t.cum for t in tables]).astype(_U64)
+    freqs = _static_tables([t for _, _, t in jobs], prob_bits)
+    cums = np.cumsum(freqs, axis=1) - freqs
+    lut = _slot_tables(freqs, prob_bits)
+    hooks.observe("codec_decode_rows", m)
     x = np.stack([np.asarray(s) for s, _, _ in jobs]).astype(_U64)
     words, wlen = _pad_words([w for _, w, _ in jobs])
     mask = _U64((1 << prob_bits) - 1)
@@ -106,10 +148,9 @@ def _decode_static_group(jobs, count: int, prob_bits: int,
     ptr = np.zeros(m, np.int64)
     rowi = np.arange(m)[:, None]
     out = np.empty((m, steps * lanes), np.uint32)
-    cums_b = cums[:, None, :]                      # (M, 1, S) for the lookup
     for t in range(steps):
         slot = x & mask
-        s = _slot_lookup(slot, cums_b)
+        s = lut[rowi, slot]
         out[:, t * lanes:(t + 1) * lanes] = s
         x = freqs[rowi, s] * (x >> pb) + slot - cums[rowi, s]
         x, ptr = _renorm(x, x < _U64(RANS_L), words, ptr, wlen)

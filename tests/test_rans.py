@@ -16,7 +16,8 @@ from repro.codec import (CorruptStream, RansContainer, RansTable,
                          rans_decode)
 from repro.codec import container as box
 from repro.codec.batch import decode_tensor_batch
-from repro.codec.rans import RANS_L, encode_static, rans_encode_rows
+from repro.codec.rans import (RANS_L, encode_static, encode_static_rows,
+                              rans_encode_rows)
 from repro.obs import hooks
 from repro.obs.metrics import MetricsRegistry
 from repro.core import codec as wire
@@ -290,6 +291,168 @@ def test_static_encode_observes_rows_once(rng, c):
     assert rows is not None and rows.count == 1 and rows.total == c
     assert encode_static_tensor(codes, 8) == blob    # nothing installed
     assert not hooks.enabled() and m.get("codec_encode_rows").count == 1
+
+
+# ---------------------------------------------------------------------------
+# batched decode: per-row slot tables against the scalar decoder
+# ---------------------------------------------------------------------------
+
+_C_BATCH = 2                                    # chunks per container
+_LANES_FOR = {1: 1, 4: 3, 64: 5, 4096: 32}      # 3 and 5 leave a part step
+
+
+def _batch_table(kind, bits, prob_bits, r):
+    nsym = 1 << bits
+    if kind == "laplace":
+        width = r.uniform(0.5, 4) * max(1, nsym // 16)
+        counts = np.floor(1e6 * np.exp(-np.abs(np.arange(nsym) - nsym / 2)
+                                       / width))
+    else:       # "spike": every symbol at frequency 1 beside a dominant one
+        counts = np.zeros(nsym)
+        counts[r.integers(nsym)] = 1
+    return RansTable.from_counts(counts, prob_bits)
+
+
+def _batch_container(tables, k, lanes, bits, r):
+    """Symbols drawn from each chunk's table, with some of the rarest mixed
+    in, coded into one static container -> (blob, symbols (C, k))."""
+    syms = np.stack([
+        r.choice(1 << bits, size=k, p=t.freqs / t.freqs.sum())
+        for t in tables]).astype(np.uint32)
+    for row, t in zip(syms, tables):                # freq-1 symbols too
+        rare = np.flatnonzero(t.freqs == t.freqs.min())
+        pos = r.choice(k, size=max(1, k // 64), replace=False)
+        row[pos] = r.choice(rare, size=pos.size)
+    states, words = encode_static_rows(syms, tables, lanes)
+    blob = box.pack_container(
+        mode=box.MODE_STATIC, bits=bits, prob_bits=tables[0].prob_bits,
+        lanes=lanes, neighbor_dist=0, tables=[t.freqs for t in tables],
+        chunks=[(k, states[i], words[i]) for i in range(len(tables))])
+    return blob, syms
+
+
+@pytest.mark.parametrize("table_kind", ["laplace", "spike"])
+@pytest.mark.parametrize("n", [1, 8])
+@pytest.mark.parametrize("k", [1, 4, 64, 4096])
+@pytest.mark.parametrize("bits,prob_bits", [
+    (2, 9), (2, 14), (2, 15), (8, 9), (8, 14), (8, 15), (12, 14), (12, 15)])
+@pytest.mark.parametrize("layout", ["per_channel", "pooled"])
+def test_batched_decode_matches_scalar_decoders(layout, bits, prob_bits, k,
+                                                n, table_kind):
+    r = np.random.default_rng([bits, prob_bits, k, n, len(table_kind),
+                               len(layout)])
+    lanes = _LANES_FOR[k]
+    blobs, syms = [], []
+    for _ in range(n):
+        tables = [_batch_table(table_kind, bits, prob_bits, r)
+                  for _ in range(1 if layout == "pooled" else _C_BATCH)]
+        tables = tables * (_C_BATCH // len(tables))
+        blob, s = _batch_container(tables, k, lanes, bits, r)
+        blobs.append(blob)
+        syms.append(s)
+    shape = (1, k, _C_BATCH)
+    out = decode_tensor_batch(blobs, shape, bits)
+    assert out.shape == (n, k * _C_BATCH)
+    for i, blob in enumerate(blobs):
+        cont = RansContainer.parse(blob)
+        # the scalar decoder looks slots up in RansTable.slot_symbols()
+        for j in range(_C_BATCH):
+            _, states, words = cont.chunk_parts(j)
+            table = RansTable(freqs=cont.chunk_table(j).astype(np.uint32),
+                              prob_bits=prob_bits)
+            ref = rans_decode(states, words, k, table, lanes)
+            assert np.array_equal(ref, syms[i][j]), (i, j)
+            assert np.array_equal(out[i].reshape(k, _C_BATCH)[:, j], ref)
+        assert np.array_equal(out[i].reshape(shape),
+                              decode_tensor(blob, shape, bits))
+
+
+def _corrupt_static(kind, r):
+    """Two good containers and one made bad in ``kind``'s way, its chunk
+    CRCs valid, so the fault reaches the batched decode loop. The fault
+    sits in container 1, chunk 1: batch row 3. Returns (blobs, shape,
+    bits)."""
+    bits, prob_bits, k, lanes = 4, 10, 96, 4
+    tables = [_batch_table("laplace", bits, prob_bits, r) for _ in range(2)]
+    blobs = [_batch_container(tables, k, lanes, bits, r)[0]
+             for _ in range(3)]
+    syms = r.integers(0, 1 << bits, size=(2, k)).astype(np.uint32)
+    states, words = encode_static_rows(syms, tables, lanes)
+    states, words = states.copy(), list(words)
+    freqs = [t.freqs.copy() for t in tables]
+    if kind == "table_sum":
+        freqs[1][0] += 1
+    elif kind == "zero_freq":
+        freqs[1][3] += freqs[1][5]
+        freqs[1][5] = 0
+    elif kind == "truncated_words":
+        words[1] = words[1][:-2]
+    elif kind == "trailing_words":
+        words[1] = words[1] + b"\x01\x00"
+    elif kind == "flipped_word":
+        # the low bit of the last word read: the renorms stay in step, so
+        # only the lane states tell
+        last = np.frombuffer(words[1], "<u2").copy()
+        last[-1] ^= 1
+        words[1] = last.tobytes()
+    blobs[1] = box.pack_container(
+        mode=box.MODE_STATIC, bits=bits, prob_bits=prob_bits, lanes=lanes,
+        neighbor_dist=0, tables=freqs,
+        chunks=[(k, states[i], words[i]) for i in range(2)])
+    return blobs, (1, k, 2), bits
+
+
+@pytest.mark.parametrize("kind,msg", [
+    ("table_sum", "frequency table of batch row 3 sums to"),
+    ("zero_freq", "frequency table of batch row 3 has zero-frequency"),
+    ("truncated_words", "truncated in batch row 3"),
+    ("trailing_words", "trailing words in batch row 3"),
+    ("flipped_word", "did not return to initial value"),
+])
+def test_batched_decode_corruption_raises_corrupt_stream(kind, msg):
+    blobs, shape, bits = _corrupt_static(kind, np.random.default_rng(17))
+    with pytest.raises(CorruptStream, match=msg):
+        decode_tensor_batch(blobs, shape, bits)
+    with pytest.raises(CorruptStream):               # the scalar path too
+        decode_tensor(blobs[1], shape, bits)
+    decode_tensor_batch([blobs[0], blobs[2]], shape, bits)
+
+
+def test_batched_decode_rejects_prob_bits_beyond_format():
+    # sums right and has no zero, but no encoder writes prob_bits 16, and
+    # its slot tables would take 2^16 entries per row
+    blob = box.pack_container(
+        mode=box.MODE_STATIC, bits=1, prob_bits=16, lanes=1, neighbor_dist=0,
+        tables=[np.array([1 << 15, 1 << 15])],
+        chunks=[(1, np.array([RANS_L], "<u4"), b"")])
+    with pytest.raises(CorruptStream, match="prob_bits 16 exceeds 15"):
+        decode_tensor_batch([blob], (1, 1), 1)
+
+
+@pytest.mark.parametrize("n,c", [(8, 128), (1, 8)])
+def test_batched_decode_observes_rows_once(n, c):
+    r = np.random.default_rng([n, c])
+    codes = [r.integers(0, 256, size=(1, 64, 64, c)).astype(np.uint32)
+             for _ in range(n)]
+    blobs = [encode_static_tensor(x, 8) for x in codes]
+    m = MetricsRegistry()
+    with hooks.active(m):
+        out = decode_tensor_batch(blobs, codes[0].shape, 8)
+    rows = m.get("codec_decode_rows")
+    assert rows is not None and rows.count == 1 and rows.total == n * c
+    assert np.array_equal(out, np.stack([x.ravel() for x in codes]))
+    decode_tensor_batch(blobs, codes[0].shape, 8)   # nothing installed
+    assert not hooks.enabled() and m.get("codec_decode_rows").count == 1
+
+
+def test_batched_decode_observes_nothing_for_ctx_containers(rng):
+    codes = rng.integers(0, 64, size=(1, 16, 16, 4)).astype(np.uint32)
+    blob = encode_adaptive_tensor(codes, 6)
+    m = MetricsRegistry()
+    with hooks.active(m):
+        out = decode_tensor_batch([blob, blob], codes.shape, 6)
+    assert np.array_equal(out[1], codes.ravel())
+    assert m.get("codec_decode_rows") is None
 
 
 def test_rans_rejects_out_of_range_codes(rng):
