@@ -1,8 +1,8 @@
 """Benchmark-trajectory gate: compare a BENCH_*.json against a baseline.
 
     PYTHONPATH=src python benchmarks/compare.py \
-        --current benchmarks/BENCH_codec.json \
-        --baseline /tmp/BENCH_codec.baseline.json [--check] [--verbose]
+        --current benchmarks/BENCH_session.json \
+        --baseline /tmp/BENCH_session.baseline.json [--check] [--verbose]
 
 Loads two ``repro-bench/1`` records (see ``repro.obs.bench``) and prints a
 per-metric trajectory report. Exit code 1 when any gated metric regressed

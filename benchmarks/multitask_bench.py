@@ -114,10 +114,9 @@ def sweep_tables(params, bank, imgs, head_cfg, head_bank) -> dict:
 
 
 def anchored_floors(tables: dict) -> dict:
-    anchor = ANCHOR.resolve()
     floors = {}
     for task, pts in tables.items():
-        at = next(p for p in pts if p.op.resolve() == anchor)
+        at = next(p for p in pts if p.op == ANCHOR)
         floors[task] = at.psnr_db - FLOOR_MARGIN_DB
     return floors
 

@@ -2,8 +2,8 @@
 
   bench_channel_sweep   Fig. 3  (accuracy vs C, n=8)
   bench_bit_sweep       Fig. 4  (accuracy + wire bits vs n, C=P/4)
-  bench_codec           Fig. 4  codec comparison (raw / tile+zlib / entropy
-                                floor / all-channels-8bit baseline of [4])
+  bench_codec           Fig. 4  codec comparison (rANS / entropy floor /
+                                all-channels-8bit baseline of [4])
   bench_consolidation   eq. (6) on/off ablation
   bench_kernels         hot-path µs/call + bandwidth-model sanity
 
@@ -71,7 +71,7 @@ def tier_a_system():
     return _SYSTEM
 
 
-def _train_and_eval(c: int, bits: int, *, consolidation=True, backend="zlib",
+def _train_and_eval(c: int, bits: int, *, consolidation=True,
                     eval_batches=None):
     """Train a BaF model for (C, n); return (accuracy, mean bits/img, stats)."""
     from repro.core.split import SplitInferenceEngine
@@ -82,7 +82,7 @@ def _train_and_eval(c: int, bits: int, *, consolidation=True, backend="zlib",
     res = train_baf(params, cnn_cfg, data_cfg, order[:c], bits=bits,
                     hidden=16, steps=steps, verbose=False)
     eng = SplitInferenceEngine(params, res.baf_params, res.sel_idx, bits=bits,
-                               backend=backend, consolidation=consolidation)
+                               consolidation=consolidation)
     it = shapes_batch_iterator(data_cfg, seed=10_000)   # same eval stream as eval_cnn
     accs, tot_bits, raw_bits, ent_bits = [], [], [], []
     psnrs, kls = [], []
@@ -152,7 +152,6 @@ def bench_bit_sweep():
 def bench_codec():
     from repro.core import codec as wire
     from repro.core.quant import compute_quant_params, quantize
-    from repro.core.tiling import tile_batch
     from repro.data.synthetic import shapes_batch_iterator
     from repro.models.cnn import cnn_edge
     cnn_cfg, data_cfg, params, order, _ = tier_a_system()
@@ -164,14 +163,11 @@ def bench_codec():
     z_sel = z[..., jnp.asarray(order[:c])]
     qp = compute_quant_params(z_sel, 8, per_example=True)
     codes = np.asarray(quantize(z_sel, qp))
-    tiled = np.asarray(tile_batch(jnp.asarray(codes)))
-    stream = tiled.reshape(-1, tiled.shape[-1])
-    for backend in ("raw", "zlib"):
-        t0 = time.perf_counter()
-        enc = wire.encode(stream, qp, backend=backend)
-        us = (time.perf_counter() - t0) * 1e6
-        out[backend] = enc.total_bits() / b
-        _row(f"codec_{backend}_C{c}", us, f"bits/img={out[backend]:.0f}")
+    t0 = time.perf_counter()
+    enc = wire.encode(codes, qp)
+    us = (time.perf_counter() - t0) * 1e6
+    out["rans"] = enc.total_bits() / b
+    _row(f"codec_rans_C{c}", us, f"bits/img={out['rans']:.0f}")
     out["entropy_floor"] = wire.empirical_entropy_bits(codes, 8) / b + c * 32
     _row(f"codec_entropy_floor_C{c}", 0.0,
          f"bits/img={out['entropy_floor']:.0f}")
@@ -179,12 +175,12 @@ def bench_codec():
     qp_all = compute_quant_params(z, 8, per_example=True)
     codes_all = np.asarray(quantize(z, qp_all))
     t0 = time.perf_counter()
-    enc_all = wire.encode(codes_all, qp_all, backend="zlib")
+    enc_all = wire.encode(codes_all, qp_all)
     us = (time.perf_counter() - t0) * 1e6
     out["all_channels_8bit"] = enc_all.total_bits() / b
     _row("codec_all_channels_8bit", us,
          f"bits/img={out['all_channels_8bit']:.0f};"
-         f"subset_saving={1 - out['zlib']/out['all_channels_8bit']:.1%}")
+         f"subset_saving={1 - out['rans']/out['all_channels_8bit']:.1%}")
     RESULTS["codec"] = out
 
 

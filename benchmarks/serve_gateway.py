@@ -19,17 +19,17 @@ Acceptance gates (ISSUE 2): 16-tenant aggregate restore throughput within
 20% of the single-tenant batched path; no tenant p99 above 3x its solo p99.
 
 Part 3 (entropy-coded serving, ISSUE 3) runs the multi-tenant gateway end
-to end with ``backend="rans"``: the rate controller selects operating
-points from an RD table built from *actual encoded container bytes*
-(cached on disk under benchmarks/, keyed by backend+seed, so CI reruns
-skip the sweep), and the scheduler/channel meter every request at its true
-container length. Reports per-backend mean wire bits and throughput, and
+to end on the rANS coder: the rate controller selects operating points
+from an RD table built from *actual encoded container bytes* (cached on
+disk under benchmarks/, keyed by the grid + seed + codec revision, so CI
+reruns skip the sweep), and the scheduler/channel meter every request at
+its true container length. Reports mean wire bits and throughput, and
 checks that scheduler grants exactly equal the containers' byte lengths.
 
 Part 4 (batched decode, ISSUE 4) measures the plan API's vectorized host
 decode: ``plan.decode_batch`` over 8 wire blobs vs 8 ``plan.decode`` calls,
 asserting bit-identical outputs and >= 1.5x decode throughput at batch 8
-(the acceptance gate, now for zlib AND the coalesced rANS batch decoder;
+(the acceptance gate, on the coalesced rANS batch decoder;
 ``--decode-only`` runs just this part for CI).
 
 Part 5 (cloud executors + overload, ISSUE 5) swaps the cloud model under
@@ -204,22 +204,22 @@ def bench_tenants(params, bank, imgs, *, n_tenants: int, c: int,
     }
 
 
-def bench_codec_backend(params, bank, imgs, *, backend: str, seed: int = 0,
+def bench_codec_backend(params, bank, imgs, *, seed: int = 0,
                         n_requests: int = 12):
     """Part 3: multi-tenant serving with real entropy-coded accounting.
 
-    The RD table is built at this backend's true container costs (and disk-
-    cached keyed by backend+seed); channel + scheduler meter each request's
-    actual serialized length.
+    The RD table is built at the rANS coder's true container costs (and
+    disk-cached, keyed by grid + seed); channel + scheduler meter each
+    request's actual serialized length.
     """
     bits_sweep = (4, 8)
     calib = imgs[:4]                 # key must match the slice actually used
     cache = os.path.join(os.path.dirname(__file__),
-                         f"rd_cache_{backend.replace('-', '_')}_seed{seed}.json")
+                         f"rd_cache_rans_seed{seed}.json")
     # the cache key is the full operating-point grid plus the codec revision
     # (load_or_build_rd_table appends the revision itself): any change to the
-    # grid, a backend's container format, or the wire profile rebuilds
-    ops = rd_grid(bank, bits_sweep, backend)
+    # grid, the container format, or the wire profile rebuilds
+    ops = rd_grid(bank, bits_sweep)
     key = {"seed": seed, "calib": int(calib.shape[0]),
            "input": int(calib.shape[1])}
     table = load_or_build_rd_table(
@@ -231,8 +231,8 @@ def bench_codec_backend(params, bank, imgs, *, backend: str, seed: int = 0,
         tenants=[TenantSpec("a"), TenantSpec("b", weight=2.0)],
         channel_cfg=ChannelConfig(bandwidth_bps=5e6, base_latency_s=0.005),
         controller=RateController(table, quality_floor_db=floor_db),
-        backend=backend, max_batch=4,
-        budget_bits_per_tick=400_000, tick_s=0.01, batch_window_s=0.005)
+        max_batch=4, budget_bits_per_tick=400_000, tick_s=0.01,
+        batch_window_s=0.005)
     work = [TenantRequest(tenant="ab"[i % 2], img=imgs[i % imgs.shape[0]],
                           t_submit=0.002 * i) for i in range(n_requests)]
     # warm every padded bucket size the measured run can hit (bursts spaced
@@ -254,7 +254,6 @@ def bench_codec_backend(params, bank, imgs, *, backend: str, seed: int = 0,
         f"scheduler grants {granted} != real container bits {wire}")
     s = tel.summary(wall_s=wall)
     return {
-        "backend": backend,
         "requests": n_requests,
         "wall_s": wall,
         "rps_end_to_end": n_requests / wall,
@@ -266,8 +265,7 @@ def bench_codec_backend(params, bank, imgs, *, backend: str, seed: int = 0,
 
 
 def bench_decode_batch(params, bank, imgs, *, c: int, bits: int = 6,
-                       backend: str = "zlib", batch: int = 8,
-                       reps: int = 40):
+                       batch: int = 8, reps: int = 40):
     """Part 4: batched vs per-request host decode (plan API).
 
     Encodes ``batch`` single-image requests at one operating point, then
@@ -282,7 +280,7 @@ def bench_decode_batch(params, bank, imgs, *, c: int, bits: int = 6,
     baf, sel = bank[c]
     spec = pipeline.ModelSpec(sel_idx=np.asarray(sel), params=params,
                               baf_params=baf)
-    op = pipeline.OperatingPoint(c=c, bits=bits, backend=backend)
+    op = pipeline.OperatingPoint(c=c, bits=bits)
     plan = pipeline.compile(op, spec)
     blobs = [plan.encode(edge(params, imgs[i % imgs.shape[0]][None]))
              for i in range(batch)]
@@ -309,7 +307,7 @@ def bench_decode_batch(params, bank, imgs, *, c: int, bits: int = 6,
     speedup = t_per / t_bat
     n = batch * reps
     return {
-        "backend": backend, "bits": bits, "batch": batch,
+        "bits": bits, "batch": batch,
         "per_request_rps": n / t_per,
         "batched_rps": n / t_bat,
         "speedup": speedup,
@@ -678,20 +676,18 @@ def main():
         return
 
     if args.decode_only:
-        # both backends carry the 1.5x gate now: zlib via unpack_bits_batch,
-        # rans via the chunk-level cross-container interleave (codec/batch.py)
-        decode_results = {}
-        for backend in ("zlib", "rans"):
-            r = bench_decode_batch(params, bank, imgs, c=c, backend=backend)
-            _row(f"gateway_decode_batch_{backend}", 1e6 / r["batched_rps"],
-                 f"per_req_rps={r['per_request_rps']:.0f} "
-                 f"batched_rps={r['batched_rps']:.0f} "
-                 f"speedup={r['speedup']:.2f}x bit_identical=True")
-            assert r["speedup"] >= 1.5, (
-                f"ACCEPTANCE FAIL: {backend} decode_batch speedup "
-                f"{r['speedup']:.2f}x below the 1.5x gate")
-            decode_results[f"decode_batch_{backend}"] = r
-        _write_gateway_bench(decode_results, args, suffix="_decode")
+        # the 1.5x gate on the chunk-level cross-container interleave
+        # (codec/batch.py)
+        r = bench_decode_batch(params, bank, imgs, c=c)
+        _row("gateway_decode_batch_rans", 1e6 / r["batched_rps"],
+             f"per_req_rps={r['per_request_rps']:.0f} "
+             f"batched_rps={r['batched_rps']:.0f} "
+             f"speedup={r['speedup']:.2f}x bit_identical=True")
+        assert r["speedup"] >= 1.5, (
+            f"ACCEPTANCE FAIL: rans decode_batch speedup "
+            f"{r['speedup']:.2f}x below the 1.5x gate")
+        _write_gateway_bench({"decode_batch_rans": r}, args,
+                             suffix="_decode")
         print("decode gate OK")
         return
 
@@ -734,33 +730,29 @@ def main():
                              BaFConvConfig(c=4, q=smoke_config().split_q,
                                            hidden=8))
         bank_multi[4] = (baf4, np.arange(4))
-    for backend in ("zlib", "rans"):
-        r = bench_codec_backend(params, bank_multi, imgs, backend=backend,
-                                n_requests=8 if args.smoke else 24)
-        results[f"codec_{backend}"] = r
-        _row(f"gateway_codec_{backend}", 1e6 * r["wall_s"] / r["requests"],
-             f"rps={r['rps_end_to_end']:.1f} "
-             f"mean_wire_bits={r['mean_wire_bits']:.0f} "
-             f"p99={r['p99_latency_ms']:.2f}ms ops={r['operating_points']} "
-             f"accounting=exact")
+    r = bench_codec_backend(params, bank_multi, imgs,
+                            n_requests=8 if args.smoke else 24)
+    results["codec_rans"] = r
+    _row("gateway_codec_rans", 1e6 * r["wall_s"] / r["requests"],
+         f"rps={r['rps_end_to_end']:.1f} "
+         f"mean_wire_bits={r['mean_wire_bits']:.0f} "
+         f"p99={r['p99_latency_ms']:.2f}ms ops={r['operating_points']} "
+         f"accounting=exact")
 
     # -- part 4: batched host decode (plan API, ISSUE 4 gate) ---------------
-    for backend in ("zlib", "rans"):
-        r = bench_decode_batch(params, bank_multi, imgs, c=c, backend=backend)
-        results[f"decode_batch_{backend}"] = r
-        _row(f"gateway_decode_batch_{backend}", 1e6 / r["batched_rps"],
-             f"per_req_rps={r['per_request_rps']:.0f} "
-             f"batched_rps={r['batched_rps']:.0f} "
-             f"speedup={r['speedup']:.2f}x bit_identical=True")
-    for backend in ("zlib", "rans"):
-        dec = results[f"decode_batch_{backend}"]
-        assert dec["speedup"] >= 1.5, (
-            f"ACCEPTANCE FAIL: {backend} decode_batch speedup "
-            f"{dec['speedup']:.2f}x at batch {dec['batch']} is below the "
-            f"1.5x gate")
-        _row(f"gateway_decode_gate_{backend}", 0.0,
-             f"decode_batch {dec['speedup']:.2f}x >= 1.5x at batch "
-             f"{dec['batch']}: OK")
+    dec = bench_decode_batch(params, bank_multi, imgs, c=c)
+    results["decode_batch_rans"] = dec
+    _row("gateway_decode_batch_rans", 1e6 / dec["batched_rps"],
+         f"per_req_rps={dec['per_request_rps']:.0f} "
+         f"batched_rps={dec['batched_rps']:.0f} "
+         f"speedup={dec['speedup']:.2f}x bit_identical=True")
+    assert dec["speedup"] >= 1.5, (
+        f"ACCEPTANCE FAIL: rans decode_batch speedup "
+        f"{dec['speedup']:.2f}x at batch {dec['batch']} is below the "
+        f"1.5x gate")
+    _row("gateway_decode_gate_rans", 0.0,
+         f"decode_batch {dec['speedup']:.2f}x >= 1.5x at batch "
+         f"{dec['batch']}: OK")
 
     # -- part 5: cloud executors + overload shedding (ISSUE 5 gates) --------
     results["overload"] = run_overload_part(

@@ -13,8 +13,9 @@
    moves to a cheaper operating point while staying at/above the floor,
 5. multi-tenant serving over one shared uplink (premium + best effort
    through the DRR scheduler),
-6. capability negotiation: a gateway that does not speak rANS downgrades
-   the operating point to zlib instead of failing on the cloud side,
+6. capability negotiation: a gateway that decodes at most 6 bits
+   downgrades an 8-bit operating point instead of failing on the cloud
+   side,
 7. overload: a 3x burst against a multi-queue cloud executor with
    priority-tiered admission — best effort browns out first, every
    rejection is an explicit RequestShed, and telemetry keeps the shed
@@ -54,7 +55,7 @@ for c in (4, 8, 16):
     print(f"  BaF trained for C={c}")
 
 print("== 1b. the plan API: one operating point, end to end ==")
-op = pipeline.OperatingPoint(c=8, bits=6, backend="rans")
+op = pipeline.OperatingPoint(c=8, bits=6)
 spec = pipeline.ModelSpec(sel_idx=np.asarray(bank[8][1]), params=params,
                           baf_params=bank[8][0])
 plan = pipeline.compile(op, spec)
@@ -66,7 +67,7 @@ blobs = [plan.encode(edge_fn(params, np.asarray(demo_imgs))) for _ in range(4)]
 decoded = plan.decode_batch(blobs)          # one vectorized host decode
 z_tilde = plan.restore(decoded)             # one jitted BaF restore
 logits = cloud_fn(params, z_tilde)
-print(f"  op {op.resolve()}")
+print(f"  op {op}")
 print(f"  4 requests -> {sum(b.nbytes for b in blobs)} wire bytes, "
       f"decode_batch {decoded.codes.shape}, logits {np.asarray(logits).shape}")
 
@@ -149,18 +150,16 @@ print(f"uplink grant shares : premium {shares['premium']:.2f}, "
 assert len(mt_resp["premium"]) == 6 and len(mt_resp["besteffort"]) == 6
 print("OK: both tenants fully served over the shared budget")
 
-print("\n== 6. capability negotiation: a zlib-only gateway meets rANS ==")
-rans_op = pipeline.OperatingPoint(c=8, bits=8, backend="rans")
-legacy = ServingGateway(params, bank, default_op=rans_op,
-                        capabilities=Capabilities(backends=("zlib",)),
-                        max_batch=4)
-resp, _ = legacy.serve(traffic[:2])
-print(f"requested {rans_op.backend!r} -> served on "
-      f"{resp[0].op.wire_backend!r} (downgraded, not refused)")
+print("\n== 6. capability negotiation: a 6-bit gateway, an 8-bit point ==")
+deep_op = pipeline.OperatingPoint(c=8, bits=8)
+shallow = ServingGateway(params, bank, default_op=deep_op,
+                         capabilities=Capabilities(max_bits=6), max_batch=4)
+resp, _ = shallow.serve(traffic[:2])
+print(f"requested {deep_op.bits} bits -> served at "
+      f"{resp[0].op.bits} bits (downgraded, not refused)")
 try:
-    ServingGateway(params, bank, default_op=rans_op,
-                   capabilities=Capabilities(backends=("zlib",),
-                                             downgrade=False))
+    ServingGateway(params, bank, default_op=deep_op,
+                   capabilities=Capabilities(max_bits=6, downgrade=False))
 except pipeline.NegotiationError as e:
     print(f"strict gateway refuses instead: {e}")
 print("OK: negotiation decided before any bytes were encoded")

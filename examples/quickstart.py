@@ -3,7 +3,8 @@
     PYTHONPATH=src python examples/quickstart.py
 
 Takes a feature tensor, selects the most-correlated channel subset (eqs. 2-3),
-quantizes + tiles + entropy-codes it (eqs. 4-5, §3.2), restores the full
+quantizes + entropy-codes it (eqs. 4-5; rANS on the channel-last codes, where
+the paper tiles them into an image for FLIF, §3.2), restores the full
 tensor with an (untrained) BaF predictor (§3.3) and consolidates the
 transmitted channels (eq. 6), printing real wire bits at every stage.
 """
@@ -16,7 +17,6 @@ from repro.core import codec as wire
 from repro.core.baf import BaFConvConfig, baf_conv_predict, init_baf_conv
 from repro.core.quant import QuantParams, compute_quant_params, dequantize, quantize
 from repro.core.selection import correlation_matrix_conv, select_channels
-from repro.core.tiling import tile_batch, untile_batch
 
 B, H, W, P, Q, C, BITS = 2, 16, 16, 64, 32, 16, 8
 
@@ -34,12 +34,11 @@ order = select_channels(rho).order
 sel = jnp.asarray(order[:C])
 print(f"selected C={C} of P={P} channels: {np.asarray(sel)[:8]}...")
 
-# 2. quantize (eq. 4) + tile (§3.2) + entropy-code
+# 2. quantize (eq. 4) + entropy-code
 z_sel = z[..., sel]
 qp = compute_quant_params(z_sel, BITS, per_example=True)
 codes = quantize(z_sel, qp)
-tiled = np.asarray(tile_batch(codes)).reshape(-1, 4 * W)  # 4x4 grid for C=16
-enc = wire.encode(tiled, qp, backend="zlib")
+enc = wire.encode(np.asarray(codes), qp)
 blob = enc.to_bytes()
 print(f"wire: {enc.total_bits():,} bits "
       f"({8 * len(enc.side_info):,} side info) -> "
@@ -47,8 +46,8 @@ print(f"wire: {enc.total_bits():,} bits "
 
 # 3. cloud: decode (eq. 5) + BaF restore (§3.3) + consolidation (eq. 6)
 dec = wire.EncodedTensor.from_bytes(blob)
-stream, qp_rx = wire.decode(dec)
-codes_rx = untile_batch(jnp.asarray(stream.reshape(B, -1, 4 * W)), C)
+codes_rx, qp_rx = wire.decode(dec)
+codes_rx = jnp.asarray(codes_rx)
 qp_rx = QuantParams(mins=jnp.asarray(qp_rx.mins).reshape(B, 1, 1, C),
                     maxs=jnp.asarray(qp_rx.maxs).reshape(B, 1, 1, C),
                     bits=BITS)

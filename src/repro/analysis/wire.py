@@ -46,8 +46,7 @@ WIRE_SCHEMA_VERSION = "repro-wire-schema/1"
 #           bytes lives. BaF2 delegates: its header is validated
 #           structurally (magic + explicit side-info/payload lengths +
 #           trailing-garbage rejection) and its payload integrity is the
-#           entropy backend's — RTC1 CRCs for rans/rans-ctx, zlib's
-#           built-in adler32 for zlib — so the delegate is the RTC1
+#           rANS container's RTC1 CRCs, so the delegate is the RTC1
 #           module. Adding a header CRC to BaF2 itself would change the
 #           wire layout and break every bit-identical gate; if that trade
 #           is ever taken it must ride a codec_revision() bump.

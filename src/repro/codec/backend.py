@@ -1,22 +1,16 @@
-"""Tensor-level rANS backends for the wire codec (core/codec.py registry).
+"""Tensor-level static rANS coding behind the wire codec (core/codec.py).
 
-Maps a channel-last code tensor onto the container's per-tile chunks:
+Maps a channel-last code tensor onto the container's per-channel chunks:
+chunk i = channel i's symbols in raster order over the leading axes (for a
+(B, H, W, C) BaF residual tensor: all of channel i, batch-major). The tensor
+is coded as it is; no tiled 2D image is built.
 
-  * chunk i = channel i's symbols in raster order over the leading axes
-    (for a (B, H, W, C) BaF residual tensor: all of channel i, batch-major);
-  * ``neighbor_dist = shape[-2]`` so the adaptive model's lane-strided
-    context is exactly the up-neighbor inside each tile row structure;
-  * ``rans``     — static per-channel frequency tables. Symbol statistics
-    come from the on-device histogram kernel (kernels/histogram.py); tables
-    travel in the container's zlib'd table blob. Encoder picks per-channel
-    tables or one shared pooled table, whichever yields fewer wire bytes
-    (small tiles can't amortize C tables) — the choice is recorded per
-    container by simply repeating the pooled table, so the decoder never
-    special-cases it.
-  * ``rans-ctx`` — context-adaptive, nothing transmitted but lane states.
-
-Unlike the image-codec backends, rANS needs no tiled 2D image: the tensor is
-coded directly and the tiling step is skipped (core/split.py).
+Tables are static and per channel. Symbol statistics come from the
+on-device histogram kernel (kernels/histogram.py); tables travel in the
+container's zlib'd table blob. The encoder picks per-channel tables or one
+shared pooled table, whichever yields fewer wire bytes (small tensors
+can't amortize C tables) — the choice is recorded per container by simply
+repeating the pooled table, so the decoder never special-cases it.
 """
 from __future__ import annotations
 
@@ -25,18 +19,17 @@ import zlib
 import numpy as np
 
 from repro.codec import container as box
-from repro.codec import context as ctx
 from repro.obs import hooks
-from repro.codec.rans import (MAX_PROB_BITS, CorruptStream, RansTable,
-                              encode_static_rows, normalize_freqs)
+from repro.codec.rans import (MAX_PROB_BITS, RANS_L, CorruptStream,
+                              RansTable, encode_static_rows, normalize_freqs)
 
 MAX_BITS = 12                # slot tables are 2^prob_bits; keep them sane
 STATIC_LANES = 32
 PROB_BITS_STATIC = 14
 
 
-def _chunk_layout(shape: tuple) -> tuple[int, int, int]:
-    """(n_chunks, symbols per chunk, up-neighbor distance) for a shape.
+def _chunk_layout(shape: tuple) -> tuple[int, int]:
+    """(n_chunks, symbols per chunk) for a shape.
 
     Channel-last for >= 2-D tensors; a 1-D/0-D stream is a SINGLE chunk
     (treating each element of a flat array as its own channel would emit a
@@ -44,29 +37,28 @@ def _chunk_layout(shape: tuple) -> tuple[int, int, int]:
     """
     if len(shape) >= 2:
         c = shape[-1]
-        k = int(np.prod(shape[:-1]))
-        return c, k, shape[-2]
+        return c, int(np.prod(shape[:-1]))
     k = shape[0] if shape else 1
-    return (1 if k else 0), k, 0
+    return (1 if k else 0), k
 
 
-def _as_symbol_matrix(codes: np.ndarray, bits: int) -> tuple[np.ndarray, int]:
-    """(..., C) -> (C, K) uint32 symbol streams + up-neighbor distance."""
+def _as_symbol_matrix(codes: np.ndarray, bits: int) -> np.ndarray:
+    """(..., C) -> (C, K) uint32 symbol streams."""
     arr = np.asarray(codes)
     if not 1 <= bits <= MAX_BITS:
-        raise ValueError(f"rans backends support 1..{MAX_BITS} bits, "
+        raise ValueError(f"the rans coder supports 1..{MAX_BITS} bits, "
                          f"got {bits}")
     if arr.size:
         amin, amax = int(arr.min()), int(arr.max())
         if amin < 0:
-            raise ValueError(f"rans backend: negative code {amin}")
+            raise ValueError(f"rans coder: negative code {amin}")
         if amax >= 1 << bits:
-            raise ValueError(f"rans backend: code {amax} does not fit "
+            raise ValueError(f"rans coder: code {amax} does not fit "
                              f"{bits} bits")
-    c, _k, neighbor = _chunk_layout(arr.shape)
+    c, _k = _chunk_layout(arr.shape)
     mat = arr.reshape(-1, c).T.astype(np.uint32) if c else \
         np.empty((0, 0), np.uint32)
-    return np.ascontiguousarray(mat), neighbor
+    return np.ascontiguousarray(mat)
 
 
 def _expected_payload_bits(counts: np.ndarray, tables: list[RansTable],
@@ -82,15 +74,16 @@ def _expected_payload_bits(counts: np.ndarray, tables: list[RansTable],
 
 
 def encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
-    """The ``rans`` backend: per-channel (or pooled) static tables."""
-    with hooks.timed("codec.encode", mode="static"):
+    """Code a channel-last tensor with per-channel (or pooled) static
+    tables -> container bytes."""
+    with hooks.timed("codec.encode"):
         return _encode_static_tensor(codes, bits)
 
 
 def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
     from repro.kernels.histogram import channel_histogram
 
-    mat, _ = _as_symbol_matrix(codes, bits)
+    mat = _as_symbol_matrix(codes, bits)
     n_ch, k = mat.shape
     # the device round trip inside encode: codes up, counts back
     with hooks.timed("codec.histogram"):
@@ -109,21 +102,20 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
     lanes = max(1, min(STATIC_LANES, k // 32 or 1, payload_guess // 64 or 1))
     prob_bits = min(MAX_PROB_BITS, max(PROB_BITS_STATIC, bits + 2))
     if n_ch == 0 or k == 0:
-        chunks = [(0, np.full(lanes, ctx.RANS_L, "<u4"), b"")] * n_ch
+        chunks = [(0, np.full(lanes, RANS_L, "<u4"), b"")] * n_ch
         tables = [normalize_freqs(np.ones(1 << bits), prob_bits)] * n_ch
-        return box.pack_container(
-            mode=box.MODE_STATIC, bits=bits, prob_bits=prob_bits,
-            lanes=lanes, neighbor_dist=0, tables=tables, chunks=chunks)
+        return box.pack_container(bits=bits, prob_bits=prob_bits,
+                                  lanes=lanes, tables=tables, chunks=chunks)
 
     def build(tables: list[RansTable]):
         # every chunk of the container in one interleaved loop
         hooks.observe("codec_encode_rows", n_ch)
         states, words = encode_static_rows(mat, tables, lanes)
         chunks = [(k, states[i], words[i]) for i in range(n_ch)]
-        return box.pack_container(
-            mode=box.MODE_STATIC, bits=bits, prob_bits=prob_bits,
-            lanes=lanes, neighbor_dist=0,
-            tables=[t.freqs for t in tables], chunks=chunks)
+        return box.pack_container(bits=bits, prob_bits=prob_bits,
+                                  lanes=lanes,
+                                  tables=[t.freqs for t in tables],
+                                  chunks=chunks)
 
     per_channel = [RansTable.from_counts(counts[i], prob_bits)
                    for i in range(n_ch)]
@@ -148,25 +140,6 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
     return build(tables)
 
 
-def encode_adaptive_tensor(codes: np.ndarray, bits: int) -> bytes:
-    """The ``rans-ctx`` backend: adaptive up-neighbor/channel context."""
-    with hooks.timed("codec.encode", mode="adaptive"):
-        return _encode_adaptive_tensor(codes, bits)
-
-
-def _encode_adaptive_tensor(codes: np.ndarray, bits: int) -> bytes:
-    mat, neighbor = _as_symbol_matrix(codes, bits)
-    n_ch, k = mat.shape
-    lanes = ctx.plan_lanes(k, neighbor)
-    chunks = []
-    for i in range(n_ch):
-        states, words = ctx.encode_ctx(mat[i], bits, lanes, neighbor)
-        chunks.append((k, states, words))
-    return box.pack_container(
-        mode=box.MODE_ADAPTIVE, bits=bits, prob_bits=ctx.ctx_prob_bits(bits),
-        lanes=lanes, neighbor_dist=neighbor, tables=None, chunks=chunks)
-
-
 def decode_tensor(payload: bytes, shape: tuple, bits: int) -> np.ndarray:
     """Decode a container back to the channel-last code tensor ``shape``."""
     with hooks.timed("codec.decode"):
@@ -179,7 +152,7 @@ def _decode_tensor(payload: bytes, shape: tuple, bits: int) -> np.ndarray:
     if h.bits != bits:
         raise CorruptStream(
             f"container codes {h.bits} bits, wire header says {bits}")
-    n_ch, k, _ = _chunk_layout(tuple(shape))
+    n_ch, k = _chunk_layout(tuple(shape))
     if h.n_chunks != n_ch:
         raise CorruptStream(
             f"container has {h.n_chunks} tile chunks, shape {shape} "
@@ -197,7 +170,7 @@ def _decode_tensor(payload: bytes, shape: tuple, bits: int) -> np.ndarray:
 
 def decode_channels(payload: bytes, indices, count: int | None = None
                     ) -> np.ndarray:
-    """Partial decode of selected tile chunks -> (len(indices), K)."""
+    """Partial decode of selected channel chunks -> (len(indices), K)."""
     cont = box.RansContainer.parse(payload)
     out = cont.decode_channels(indices)
     if count is not None and out.size and out.shape[1] != count:

@@ -1,30 +1,22 @@
 """Cross-container batched rANS decode — chunk-level interleave.
 
-``decode_many`` used to fall back to a per-blob loop for the rANS backends:
-each container's chunks decode one after another, and every chunk pays the
-full python-loop overhead of its ``steps = count / lanes`` interleave steps
-at a vector width of only ``lanes`` (often 2-8 on small BaF tiles). A
-micro-batch bucket of N same-shape containers therefore runs
-``N * C * steps`` tiny numpy dispatches.
+Decoding a micro-batch bucket one container at a time runs each chunk's
+``steps = count / lanes`` interleave steps as tiny numpy dispatches at a
+vector width of only ``lanes`` (often 2-8 on small BaF tensors): ``N * C *
+steps`` dispatches for N same-shape containers of C channels.
 
 This module coalesces the interleave across *all* chunks of *all*
 containers in the batch: chunks with identical coding geometry (lanes,
-probability resolution, symbol count, context distance) stack into one
-``(M, lanes)`` state matrix and the decode loop runs ``steps`` iterations
-total at vector width ``M * lanes`` — each chunk still consumes its own
-word stream through a per-row pointer, so outputs are bit-identical to the
-per-blob decoder (the batched pipeline's hard invariant).
+probability resolution) stack into one ``(M, lanes)`` state matrix and the
+decode loop runs ``steps`` iterations total at vector width ``M * lanes``
+— each chunk still consumes its own word stream through a per-row pointer,
+so outputs are bit-identical to the per-blob decoder (the batched
+pipeline's hard invariant).
 
-Static-table chunks and adaptive-context chunks batch separately. A static
-group builds one slot -> symbol table per row, once, in a single vectorized
-``np.repeat`` (``M x 2^prob_bits`` entries, one byte each up to 8 bits and
-two above: 16 MiB for 1,024 chunks at ``prob_bits`` 14, freed when the group
-returns), so each step decodes every lane with one gather. Within the
-adaptive group the per-chunk adaptation state (context counts, tables)
-carries a leading batch axis and refreshes on the same schedule as the
-scalar model, so encoder/decoder symmetry is preserved by construction; its
-tables change at every refresh, so it looks slots up by comparing with the
-cumulative frequencies instead (:func:`_slot_lookup`).
+Each group builds one slot -> symbol table per row, once, in a single
+vectorized ``np.repeat`` (``M x 2^prob_bits`` entries, one byte each up to
+8 bits and two above: 16 MiB for 1,024 chunks at ``prob_bits`` 14, freed
+when the group returns), so each step decodes every lane with one gather.
 
 All integrity checks of the scalar path run here too: container/chunk CRCs
 (via ``RansContainer.chunk_parts``), word-stream exhaustion, and the
@@ -35,7 +27,6 @@ from __future__ import annotations
 import numpy as np
 
 from repro.codec import container as box
-from repro.codec import context as ctx
 from repro.codec.backend import _chunk_layout
 from repro.codec.rans import MAX_PROB_BITS, RANS_L, WORD_BITS, CorruptStream
 from repro.obs import hooks
@@ -81,19 +72,6 @@ def _finish_checks(x, ptr, wlen):
         raise CorruptStream(
             "rANS lane states did not return to initial value "
             "(corrupt payload)")
-
-
-def _slot_lookup(slot: np.ndarray, cums_rows: np.ndarray) -> np.ndarray:
-    """Slot -> symbol by comparing with each row's cumulative frequencies.
-
-    ``cums_rows`` is each row's exclusive cumulative-frequency array; the
-    decoded symbol is the last one whose cum <= slot. Only the adaptive
-    group uses it: its tables are rebuilt at every refresh, so a slot table
-    would be rebuilt as often, while the compare is O(S) per lane and needs
-    no state. Static groups gather from slot tables built once
-    (:func:`_slot_tables`)."""
-    return (np.sum(slot[..., None] >= cums_rows, axis=-1) - 1).astype(
-        np.int64)
 
 
 def _static_tables(tables: "list[np.ndarray]",
@@ -158,68 +136,12 @@ def _decode_static_group(jobs, count: int, prob_bits: int,
     return out[:, :count]
 
 
-def _decode_adaptive_group(jobs, count: int, bits: int, lanes: int,
-                           neighbor_dist: int) -> np.ndarray:
-    """jobs: [(states, words bytes)] -> (M, count), adaptive context model.
-
-    The batch axis rides in front of the scalar model's state
-    (``counts/freqs/cums/slot_tables``); adaptation math and the refresh
-    schedule are the scalar model's, row for row, so every row decodes
-    exactly as the per-blob path would."""
-    m = len(jobs)
-    neighbor_dist = ctx._normalize_neighbor(lanes, neighbor_dist)
-    steps = -(-count // lanes)
-    nsym = 1 << bits
-    nctx = ctx._n_ctx(bits)
-    shift = ctx._ctx_shift(bits)
-    prob_bits = ctx.ctx_prob_bits(bits)
-    refresh_every = max(1, ctx.REFRESH_SYMBOLS // lanes)
-    counts = np.ones((m, nctx, nsym), np.int64)
-    freqs = np.empty((m, nctx, nsym), np.uint64)
-    cums = np.empty((m, nctx, nsym), np.uint64)
-
-    def rebuild_freqs():
-        # the scalar model's own rebuild, once per batch row — adaptation
-        # math stays single-sourced in repro.codec.context
-        for r in range(m):
-            ctx.rebuild_tables(counts[r], prob_bits, freqs[r], cums[r])
-
-    rebuild_freqs()
-    x = np.stack([np.asarray(s) for s, _ in jobs]).astype(_U64)
-    words, wlen = _pad_words([w for _, w in jobs])
-    mask = _U64((1 << prob_bits) - 1)
-    pb = _U64(prob_bits)
-    ptr = np.zeros(m, np.int64)
-    rowi = np.arange(m)[:, None]
-    base = np.arange(lanes, dtype=np.int64)
-    out = np.empty((m, steps * lanes), np.uint32)
-    for t in range(steps):
-        if t and ctx.refresh_due(t, refresh_every):
-            rebuild_freqs()
-        idx = t * lanes + base
-        if neighbor_dist < 1:
-            cxv = np.full((m, lanes), nctx - 1, np.int64)
-        else:
-            nb = idx - neighbor_dist
-            has = nb >= 0
-            cxv = np.full((m, lanes), nctx - 1, np.int64)
-            cxv[:, has] = out[:, nb[has]].astype(np.int64) >> shift
-        slot = x & mask
-        s = _slot_lookup(slot, cums[rowi, cxv])
-        x = freqs[rowi, cxv, s] * (x >> pb) + slot - cums[rowi, cxv, s]
-        x, ptr = _renorm(x, x < _U64(RANS_L), words, ptr, wlen)
-        out[:, idx] = s
-        np.add.at(counts, (rowi, cxv, s), ctx.COUNT_INCREMENT)
-    _finish_checks(x, ptr, wlen)
-    return out[:, :count]
-
-
 def decode_tensor_batch(payloads: "list[bytes]", shape: tuple,
                         bits: int) -> np.ndarray:
     """Decode N same-shape containers -> (N, prod(shape)) channel-last rows.
 
-    The backend's ``decode_batch`` hook (core/codec.py registry): output
-    row i equals ``decode_tensor(payloads[i], shape, bits).ravel()`` bit for
+    The decode behind ``core/codec.py``'s ``decode_many``: output row i
+    equals ``decode_tensor(payloads[i], shape, bits).ravel()`` bit for
     bit, but all compatible chunks across the whole batch share one
     interleaved decode loop."""
     with hooks.timed("codec.decode_batch"):
@@ -229,7 +151,7 @@ def decode_tensor_batch(payloads: "list[bytes]", shape: tuple,
 def _decode_tensor_batch(payloads: "list[bytes]", shape: tuple,
                          bits: int) -> np.ndarray:
     shape = tuple(shape)
-    n_ch, k, _ = _chunk_layout(shape)
+    n_ch, k = _chunk_layout(shape)
     count_total = int(np.prod(shape)) if shape else 1
     conts = [box.RansContainer.parse(p) for p in payloads]
     for cont in conts:
@@ -254,29 +176,17 @@ def _decode_tensor_batch(payloads: "list[bytes]", shape: tuple,
         return np.zeros((n, count_total), np.uint32)
     mats = np.empty((n, n_ch, k), np.uint32)
     # group chunks by coding geometry; each group shares one decode loop
-    static_groups: dict = {}
-    adaptive_groups: dict = {}
+    groups: dict = {}
     for i, cont in enumerate(conts):
         h = cont.header
         for j in range(h.n_chunks):
             _count, states, words = cont.chunk_parts(j)   # CRC-verified
-            if h.mode == box.MODE_STATIC:
-                key = (h.prob_bits, h.lanes)
-                static_groups.setdefault(key, []).append(
-                    ((i, j), (states, words, cont.chunk_table(j))))
-            else:
-                key = (h.lanes, h.neighbor_dist)
-                adaptive_groups.setdefault(key, []).append(
-                    ((i, j), (states, words)))
+            groups.setdefault((h.prob_bits, h.lanes), []).append(
+                ((i, j), (states, words, cont.chunk_table(j))))
     # all grouped chunks' lanes decode in one vector pass
-    for (prob_bits, lanes), entries in static_groups.items():
+    for (prob_bits, lanes), entries in groups.items():
         rows = _decode_static_group([job for _, job in entries], k,
                                     prob_bits, lanes)
-        for (i, j), row in zip((pos for pos, _ in entries), rows):
-            mats[i, j] = row
-    for (lanes, neighbor), entries in adaptive_groups.items():
-        rows = _decode_adaptive_group([job for _, job in entries], k, bits,
-                                      lanes, neighbor)
         for (i, j), row in zip((pos for pos, _ in entries), rows):
             mats[i, j] = row
     # channel-last reassembly, one transpose over the whole stack

@@ -1,21 +1,22 @@
-"""Versioned rANS bitstream container — per-tile chunks, partial decode.
+"""Versioned rANS bitstream container — per-channel chunks, partial decode.
 
 Layout (all little-endian)::
 
     header   "RTC1" | u8 version | u8 mode | u8 bits | u8 prob_bits |
              u16 lanes | u16 neighbor_dist | u32 n_chunks | u32 table_len |
              u32 crc32(header fields above)
-    tables   zlib(table blob)   # static mode: n_chunks tables of
-                                # (1 << bits) uint16 frequencies each;
-                                # adaptive mode: empty (nothing transmitted)
+    tables   zlib(table blob)   # n_chunks tables of (1 << bits) uint16
+                                # frequencies each
     chunk[i] u32 count | u32 n_words | u32 crc32(count|n_words|states|words)
              | lanes * u32 lane states | n_words * u16 rANS words
 
-One chunk per tile (= channel plane of the BaF residual tensor, matching
-``core/tiling.py``'s channel tiles). Chunk boundaries are computable from
-the fixed-size chunk headers alone, so a decoder can skip straight to any
-subset of tiles (:meth:`RansContainer.decode_channels`) without touching the
-other payloads — the table blob is the only shared section.
+One chunk per channel plane of the BaF residual tensor. ``mode`` is always
+0 (static tables) and ``neighbor_dist`` always 0; both header fields stay
+so that the layout, and every container byte, is unchanged. Chunk
+boundaries are computable from the fixed-size chunk headers alone, so a
+decoder can skip straight to any subset of channels
+(:meth:`RansContainer.decode_channels`) without touching the other
+payloads — the table blob is the only shared section.
 
 Every structural violation raises :class:`CorruptStream` with a distinct
 message: bad magic, unknown version/mode, truncated header, truncated table
@@ -32,13 +33,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from repro.codec import context as ctx
 from repro.codec.rans import RANS_L, CorruptStream, RansTable, rans_decode
 
 MAGIC = b"RTC1"
 VERSION = 1
 MODE_STATIC = 0
-MODE_ADAPTIVE = 1
 
 _HEADER = struct.Struct("<4sBBBBHHII")
 _HEADER_CRC = struct.Struct("<I")
@@ -47,31 +46,28 @@ _CHUNK_HEADER = struct.Struct("<III")     # count | n_words | crc32
 
 @dataclass(frozen=True)
 class ContainerHeader:
-    mode: int
     bits: int
     prob_bits: int
     lanes: int
-    neighbor_dist: int
     n_chunks: int
 
 
-def pack_container(*, mode: int, bits: int, prob_bits: int, lanes: int,
-                   neighbor_dist: int,
-                   tables: list[np.ndarray] | None,
+def pack_container(*, bits: int, prob_bits: int, lanes: int,
+                   tables: list[np.ndarray],
                    chunks: list[tuple[int, np.ndarray, bytes]]) -> bytes:
     """Assemble the wire blob.
 
-    tables : per-chunk frequency arrays (static mode) or None (adaptive)
+    tables : per-chunk frequency arrays
     chunks : [(symbol count, lane states (lanes,) uint32, word bytes)]
     """
-    if tables is not None and len(tables) != len(chunks):
+    if len(tables) != len(chunks):
         raise ValueError(f"{len(tables)} tables for {len(chunks)} chunks")
     table_blob = b""
-    if tables is not None and tables:
+    if tables:
         raw = np.concatenate([t.astype("<u2") for t in tables]).tobytes()
         table_blob = zlib.compress(raw, 9)
-    hdr = _HEADER.pack(MAGIC, VERSION, mode, bits, prob_bits, lanes,
-                       neighbor_dist, len(chunks), len(table_blob))
+    hdr = _HEADER.pack(MAGIC, VERSION, MODE_STATIC, bits, prob_bits, lanes,
+                       0, len(chunks), len(table_blob))
     out = [hdr, _HEADER_CRC.pack(zlib.crc32(hdr)), table_blob]
     for count, states, words in chunks:
         if len(words) % 2:
@@ -101,7 +97,7 @@ class RansContainer:
             raise CorruptStream(
                 f"truncated container header: {len(blob)} bytes, "
                 f"need {hdr_size}")
-        (magic, version, mode, bits, prob_bits, lanes, neighbor_dist,
+        (magic, version, mode, bits, prob_bits, lanes, _neighbor_dist,
          n_chunks, table_len) = _HEADER.unpack_from(blob, 0)
         if magic != MAGIC:
             raise CorruptStream(f"bad container magic {magic!r}")
@@ -110,7 +106,7 @@ class RansContainer:
         (hdr_crc,) = _HEADER_CRC.unpack_from(blob, _HEADER.size)
         if hdr_crc != zlib.crc32(blob[:_HEADER.size]):
             raise CorruptStream("container header CRC mismatch")
-        if mode not in (MODE_STATIC, MODE_ADAPTIVE):
+        if mode != MODE_STATIC:
             raise CorruptStream(f"unknown container mode {mode}")
         if not 1 <= bits <= 16 or lanes < 1:
             raise CorruptStream(
@@ -121,7 +117,7 @@ class RansContainer:
                 f"truncated table blob: header claims {table_len} bytes, "
                 f"{len(blob) - off} remain")
         tables: list[np.ndarray] = []
-        if mode == MODE_STATIC and n_chunks:
+        if n_chunks:
             try:
                 raw = zlib.decompress(blob[off:off + table_len])
             except zlib.error as e:
@@ -134,8 +130,6 @@ class RansContainer:
                     f"{nsym} uint16)")
             flat = np.frombuffer(raw, "<u2").reshape(n_chunks, nsym)
             tables = [flat[i] for i in range(n_chunks)]
-        elif table_len and mode == MODE_ADAPTIVE:
-            raise CorruptStream("adaptive container carries a table blob")
         off += table_len
         chunk_meta = []
         for i in range(n_chunks):
@@ -156,9 +150,8 @@ class RansContainer:
             raise CorruptStream(
                 f"{len(blob) - off} bytes of trailing garbage after "
                 f"chunk {n_chunks - 1 if n_chunks else 'header'}")
-        header = ContainerHeader(mode=mode, bits=bits, prob_bits=prob_bits,
-                                 lanes=lanes, neighbor_dist=neighbor_dist,
-                                 n_chunks=n_chunks)
+        header = ContainerHeader(bits=bits, prob_bits=prob_bits,
+                                 lanes=lanes, n_chunks=n_chunks)
         return cls(header, tables, chunk_meta, blob)
 
     # -- decode -------------------------------------------------------------
@@ -190,25 +183,23 @@ class RansContainer:
                     f"chunk {i}: empty chunk with non-initial lane states")
         return count, states, words
 
-    def chunk_table(self, i: int) -> np.ndarray | None:
-        """Static-mode frequency table of chunk ``i`` (None when adaptive)."""
-        return self._tables[i] if self.header.mode == MODE_STATIC else None
+    def chunk_table(self, i: int) -> np.ndarray:
+        """Frequency table of chunk ``i``."""
+        return self._tables[i]
 
     def decode_chunk(self, i: int) -> np.ndarray:
-        """Decode tile ``i`` alone; other chunks are never touched."""
+        """Decode channel ``i`` alone; other chunks are never touched."""
         h = self.header
         count, states, words = self.chunk_parts(i)
         if count == 0:
             return np.empty(0, np.uint32)
-        if h.mode == MODE_STATIC:
-            table = RansTable(freqs=self._tables[i].astype(np.uint32),
-                              prob_bits=h.prob_bits)
-            return rans_decode(states, words, count, table, h.lanes)
-        return ctx.decode_ctx(states, words, count, h.bits, h.lanes,
-                              h.neighbor_dist)
+        table = RansTable(freqs=self._tables[i].astype(np.uint32),
+                          prob_bits=h.prob_bits)
+        return rans_decode(states, words, count, table, h.lanes)
 
     def decode_channels(self, indices) -> np.ndarray:
-        """Partial decode: (len(indices), count) for the requested tiles."""
+        """Partial decode: (len(indices), count) for the requested
+        channels."""
         rows = [self.decode_chunk(int(i)) for i in indices]
         return np.stack(rows) if rows else np.empty((0, 0), np.uint32)
 

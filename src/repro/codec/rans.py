@@ -15,9 +15,9 @@ Construction (all little-endian):
     reverse and lays its renorm words out forward, step by step and in
     increasing lane order within a step, so the decoder (walking forward)
     reads them with a single pointer.
-  * the encoder takes *per-symbol* (freq, cumfreq) arrays — one static table
-    (``RansTable``) or a context model (repro.codec.context) both reduce to
-    a gather before the coding loop, so the loop itself is model-agnostic.
+  * the encoder takes *per-symbol* (freq, cumfreq) arrays — a static table
+    (``RansTable``) reduces to a gather before the coding loop, so the loop
+    itself knows nothing of tables.
   * the encoder codes M streams of one length at once (``rans_encode_rows``):
     M stacked rows x N lanes of state advance through one reverse loop of
     K / N steps, and each row's renorm words are picked out afterwards, so a
@@ -53,8 +53,8 @@ def normalize_freqs(counts: np.ndarray, prob_bits: int) -> np.ndarray:
     """Scale histogram ``counts`` to sum exactly to ``1 << prob_bits``.
 
     Every symbol of the alphabet gets frequency >= 1 (even zero-count ones),
-    so the resulting table can code *any* symbol — required for lane padding
-    and for adaptive models that may meet unseen symbols. Deterministic:
+    so the resulting table can code *any* symbol — required for lane
+    padding. Deterministic:
     ties break by symbol index, so encoder and decoder derive identical
     tables from identical counts.
     """
@@ -129,26 +129,15 @@ class RansTable:
         return self._slots
 
 
-def pad_to_lanes(symbols: np.ndarray, lanes: int,
-                 pad_value: int) -> np.ndarray:
-    """Pad the symbol stream to a whole number of interleave steps."""
-    k = symbols.size
-    rem = (-k) % lanes
-    if rem == 0:
-        return symbols
-    return np.concatenate(
-        [symbols, np.full(rem, pad_value, dtype=symbols.dtype)])
-
-
 def rans_encode_rows(freqs: np.ndarray, cums: np.ndarray, prob_bits: int,
                      lanes: int) -> tuple[np.ndarray, list[bytes]]:
     """Encode M symbol streams at once given their (freq, cumfreq) gathers.
 
-    freqs/cums: (M, K) with K a multiple of ``lanes`` (callers pad, see
-    :func:`pad_to_lanes`); row m holds stream m, entry (m, i) its symbol i.
-    One reverse interleave loop of K / lanes steps codes all M rows side by
-    side, M x lanes states wide; each row keeps its own lane states and its
-    own word stream. Returns ``(final lane states (M, lanes) uint32, [word
+    freqs/cums: (M, K) with K a multiple of ``lanes`` (callers pad, as
+    :func:`encode_static_rows` does); row m holds stream m, entry (m, i)
+    its symbol i. One reverse interleave loop of K / lanes steps codes all
+    M rows side by side, M x lanes states wide; each row keeps its own lane
+    states and its own word stream. Returns ``(final lane states (M, lanes) uint32, [word
     stream bytes of row m])``, row m exactly what coding it alone gives.
     """
     f = np.asarray(freqs)

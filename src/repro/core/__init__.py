@@ -4,7 +4,6 @@ from repro.core.quant import (QuantParams, compute_quant_params, quantize,
 from repro.core.selection import (SelectionResult, correlation_matrix_conv,
                                   correlation_matrix_stream, select_channels,
                                   select_channels_greedy, accumulate_correlation)
-from repro.core.tiling import tile_grid, tile_channels, untile_channels, tile_batch, untile_batch
 from repro.core.losses import charbonnier
 from repro.core.baf import (BaFConvConfig, BaFStreamConfig, init_baf_conv,
                             init_baf_stream, baf_conv_predict, baf_stream_predict,

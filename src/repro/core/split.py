@@ -1,4 +1,4 @@
-"""Split-inference engine: edge -> (quantize/tile/entropy-code) -> channel ->
+"""Split-inference engine: edge -> (quantize/entropy-code) -> channel ->
 (decode/dequantize) -> BaF restore -> cloud.  Paper Fig. 1, end to end.
 
 Device-side math (quantize, BaF, consolidation) is jit-able JAX; the entropy
@@ -180,11 +180,10 @@ class SplitInferenceEngine:
     baf_params : trained BaF predictor params (core/baf.py)
     sel_idx : ordered selected-channel indices (core/selection.py), length C
     bits : quantizer depth n
-    backend : wire codec backend ('zlib' | 'png' | 'raw' | 'rans' | ...)
     """
 
     def __init__(self, params, baf_params, sel_idx, *, bits: int = 8,
-                 backend: str = "zlib", consolidation: bool = True):
+                 consolidation: bool = True):
         from repro import pipeline                     # lazy: avoid cycle
         from repro.models.cnn import cnn_cloud
         self._edge_fn = jax.jit(edge_forward)
@@ -193,10 +192,9 @@ class SplitInferenceEngine:
         self.baf_params = baf_params
         self.sel_idx = jnp.asarray(np.asarray(sel_idx), jnp.int32)
         self.bits = bits
-        self.backend = backend
         self.consolidation = consolidation
         self.op = pipeline.OperatingPoint(c=int(self.sel_idx.shape[0]),
-                                          bits=bits, backend=backend)
+                                          bits=bits)
         self.spec = pipeline.ModelSpec(sel_idx=np.asarray(sel_idx),
                                        params=params, baf_params=baf_params)
         self.plan = pipeline.compile(self.op, self.spec, fused=False,
@@ -210,15 +208,8 @@ class SplitInferenceEngine:
         return blob, blob.stats
 
     # -- cloud side ----------------------------------------------------------
-    def decode_and_infer(self, enc, batch: int):
-        """Decode + BaF restore + cloud forward.
-
-        Accepts a plan ``WireBlob`` or a bare ``EncodedTensor`` (legacy
-        callers that shipped raw wire tensors around).
-        """
-        from repro import pipeline
-        blob = (enc if isinstance(enc, pipeline.WireBlob)
-                else pipeline.blob_from_tensor(enc, self.op, batch))
+    def decode_and_infer(self, blob):
+        """Decode a plan ``WireBlob`` + BaF restore + cloud forward."""
         z_tilde = self.plan.restore(self.plan.decode(blob))
         return self._cloud_fn(self.params, z_tilde)
 
@@ -234,5 +225,5 @@ class SplitInferenceEngine:
         blob, stats = self.encode(img)
         # decode parses blob.data through EncodedTensor.from_bytes — the
         # actual wire round-trip, header validation included
-        logits = self.decode_and_infer(blob, batch=img.shape[0])
+        logits = self.decode_and_infer(blob)
         return logits, stats

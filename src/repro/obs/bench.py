@@ -5,12 +5,12 @@ Every benchmark under benchmarks/ writes one record per run:
 
     {
       "schema": "repro-bench/1",
-      "name": "codec",
+      "name": "session",
       "git_sha": "<HEAD or 'unknown'>",
       "config": {...inputs that must match for a comparison to be fair...},
       "metrics": {
-        "rans_vs_zlib_8bit": {"value": 0.82, "better": "lower",
-                               "tolerance": 0.05},
+        "p_over_i_bits": {"value": 0.42, "better": "lower",
+                          "tolerance": 0.05},
         ...
       },
       "raw": {...optional, full benchmark output, never compared...}

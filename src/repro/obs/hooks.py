@@ -7,7 +7,7 @@ without polluting the pipeline API. This module gives them a process-global
 hook instead:
 
     from repro.obs import hooks
-    with hooks.timed("pipeline.encode", backend=op.wire_backend):
+    with hooks.timed("pipeline.encode"):
         ...body...
 
 When no registry is installed (the default), ``timed`` returns one shared

@@ -2,19 +2,13 @@
 
 An :class:`OperatingPoint` is the single value object that names *everything*
 about how one request's split activation is coded on the wire: how many
-channels travel (C), the quantizer depth (n), which entropy backend codes the
-stream, whether the channels are tiled into a 2D image first, which context
-model the coder runs, and which wire-profile generation the container speaks.
-Before this existed, ``(C, bits, backend)`` tuples were re-plumbed by hand
-through core/split.py, core/codec.py, and every serve/ call site.
-
-``auto`` fields resolve from the backend registry (``resolve()``), so callers
-write ``OperatingPoint(c=8, bits=6, backend="rans")`` and the pipeline fills
-in the tiling detour and context mode the backend needs.
+channels travel (C), the quantizer depth (n), and which wire-profile
+generation the container speaks. The entropy coder is always static rANS on
+the channel-last tensor (repro.codec).
 
 Capability negotiation lets a gateway refuse — or, when allowed, downgrade —
-an operating point whose wire profile or backend it does not speak, instead
-of failing deep inside the codec on the cloud side.
+an operating point whose wire profile or bit depth it does not speak,
+instead of failing deep inside the codec on the cloud side.
 """
 from __future__ import annotations
 
@@ -32,9 +26,6 @@ WIRE_PROFILE_VERSION = 2
 # the temporal-delta framing, in which case sessions fall back to I-only.
 SESSION_WIRE_VERSION = 1
 
-_TILING_MODES = ("auto", "tiled", "direct")
-_CONTEXT_MODES = ("auto", "none", "static", "adaptive")
-
 
 class NegotiationError(ValueError):
     """The gateway cannot serve this operating point and may not downgrade."""
@@ -44,20 +35,14 @@ class NegotiationError(ValueError):
 class OperatingPoint:
     """One coding configuration, end to end.
 
-    c        : transmitted channels (power of two; tiling constraint)
+    c        : transmitted channels
     bits     : quantizer depth n
-    backend  : entropy backend family ('zlib' | 'png' | 'raw' | 'rans' | ...)
-    tiling   : 'auto' resolves from the backend ('tiled' = 2D image detour,
-               'direct' = channel-last tensor coded as-is)
-    context  : 'auto' resolves from the backend; 'adaptive' upgrades 'rans'
-               to the context-adaptive coder ('rans-ctx' on the wire)
+    backend  : the entropy coder; only ``"rans"`` (static tables) exists
     profile  : wire-profile generation this point's containers speak
     """
     c: int
     bits: int
-    backend: str = "zlib"
-    tiling: str = "auto"
-    context: str = "auto"
+    backend: str = "rans"
     profile: int = WIRE_PROFILE_VERSION
 
     def __post_init__(self):
@@ -65,51 +50,9 @@ class OperatingPoint:
             raise ValueError(f"c must be >= 1, got {self.c}")
         if not 1 <= self.bits <= 16:
             raise ValueError(f"bits must be in 1..16, got {self.bits}")
-        if self.tiling not in _TILING_MODES:
-            raise ValueError(f"tiling must be one of {_TILING_MODES}, "
-                             f"got {self.tiling!r}")
-        if self.context not in _CONTEXT_MODES:
-            raise ValueError(f"context must be one of {_CONTEXT_MODES}, "
-                             f"got {self.context!r}")
-
-    # -- resolution ---------------------------------------------------------
-    @property
-    def wire_backend(self) -> str:
-        """Registry name of the backend that actually codes the stream.
-
-        ``context='adaptive'`` upgrades the static 'rans' family to the
-        context-adaptive coder; every other combination passes through.
-        """
-        if self.backend == "rans" and self.context == "adaptive":
-            return "rans-ctx"
-        return self.backend
-
-    def resolve(self) -> "OperatingPoint":
-        """Fill every ``auto`` field from the backend registry."""
-        from repro.core import codec as wire
-        tiling = self.tiling
-        if tiling == "auto":
-            tiling = ("tiled" if wire.backend_wants_tiling(self.wire_backend)
-                      else "direct")
-        if tiling == "tiled" and (self.c & (self.c - 1)) != 0:
-            raise ValueError(
-                f"backend {self.wire_backend!r} tiles the channels into a 2D "
-                f"image, which requires a power-of-two C (got {self.c}); "
-                f"use a direct backend such as 'rans' for this C")
-        context = self.context
-        if context == "auto":
-            context = {"rans": "static", "rans-ctx": "adaptive"}.get(
-                self.backend, "none")
-        if tiling == self.tiling and context == self.context:
-            return self
-        return dataclasses.replace(self, tiling=tiling, context=context)
-
-    def with_backend(self, backend: str) -> "OperatingPoint":
-        """Same point on a different backend; tiling/context re-resolve."""
-        if backend == self.backend:
-            return self
-        return dataclasses.replace(self, backend=backend, tiling="auto",
-                                   context="auto")
+        if self.backend != "rans":
+            raise ValueError(f"unknown backend {self.backend!r}; the only "
+                             f"entropy coder is 'rans'")
 
 
 @dataclass(frozen=True)
@@ -117,11 +60,9 @@ class Capabilities:
     """What one gateway (or decoder) can speak.
 
     profiles  : wire-profile generations the decode side understands
-    backends  : entropy backends it can decode (None = everything registered);
-                order matters — the first entry is the downgrade target
     max_bits  : deepest quantizer it will decode
-    downgrade : whether :func:`negotiate` may substitute a supported backend
-                / shallower bit depth instead of refusing
+    downgrade : whether :func:`negotiate` may substitute a shallower bit
+                depth instead of refusing
     session_profiles : SessionFrame framing generations the decode side
                 speaks (empty tuple = no temporal P-frames; sessions run
                 I-only when downgrade is allowed)
@@ -131,7 +72,6 @@ class Capabilities:
                 allowed, refused otherwise (:func:`negotiate_tasks`)
     """
     profiles: tuple = (WIRE_PROFILE_VERSION,)
-    backends: tuple | None = None
     max_bits: int = 16
     downgrade: bool = True
     session_profiles: tuple = (SESSION_WIRE_VERSION,)
@@ -140,17 +80,14 @@ class Capabilities:
     def serves_task(self, name: str) -> bool:
         return self.task_heads is None or name in self.task_heads
 
-    def speaks_backend(self, name: str) -> bool:
-        return self.backends is None or name in self.backends
-
 
 def negotiate(op: OperatingPoint, caps: Capabilities | None) -> OperatingPoint:
     """Fit ``op`` to ``caps``: pass through, downgrade, or refuse.
 
     A wire-profile mismatch always refuses — there is no lower profile to
-    fall back to, the container format itself is foreign. Backend and bit
-    depth downgrade to the capabilities' preferred backend / max depth when
-    ``caps.downgrade`` allows it, otherwise raise :class:`NegotiationError`.
+    fall back to, the container format itself is foreign. The bit depth
+    downgrades to the capabilities' max depth when ``caps.downgrade`` allows
+    it, otherwise :class:`NegotiationError` is raised.
     """
     if caps is None:
         return op
@@ -158,34 +95,13 @@ def negotiate(op: OperatingPoint, caps: Capabilities | None) -> OperatingPoint:
         raise NegotiationError(
             f"gateway speaks wire profiles {caps.profiles}, operating point "
             f"requires profile {op.profile}")
-    out = op
-    if not caps.speaks_backend(out.wire_backend):
-        if not caps.downgrade or not caps.backends:
-            raise NegotiationError(
-                f"gateway cannot decode backend {out.wire_backend!r} "
-                f"(speaks {caps.backends}) and downgrade is disabled")
-        # full re-base, context included: downgrading 'rans'+adaptive to
-        # plain 'rans' must also drop the context upgrade that made the
-        # wire backend unsupported in the first place
-        out = dataclasses.replace(out, backend=caps.backends[0],
-                                  tiling="auto", context="auto")
-    if out.bits > caps.max_bits:
+    if op.bits > caps.max_bits:
         if not caps.downgrade:
             raise NegotiationError(
                 f"gateway decodes at most {caps.max_bits} bits, operating "
-                f"point requires {out.bits}")
-        out = dataclasses.replace(out, bits=caps.max_bits)
-    try:
-        # negotiation promises a servable point: a downgrade that lands on
-        # a backend unable to code this C (e.g. rans C=12 -> tiled zlib,
-        # which needs a power-of-two C) must refuse here, not blow up with
-        # a ValueError at plan-compile time
-        out.resolve()
-    except ValueError as e:
-        raise NegotiationError(
-            f"no supported backend can serve this operating point: {e}"
-        ) from None
-    return out
+                f"point requires {op.bits}")
+        return dataclasses.replace(op, bits=caps.max_bits)
+    return op
 
 
 def negotiate_session(caps: Capabilities | None, *,
@@ -216,7 +132,7 @@ def negotiate_tasks(tasks, caps: Capabilities | None) -> tuple:
     through bit allocation, only pays for that subset; with downgrade
     disabled, or when nothing declared survives, the whole declaration is
     refused (:class:`NegotiationError`). Task negotiation never touches the
-    operating point — wire-profile and backend fitting stay in
+    operating point — wire-profile and bit-depth fitting stay in
     :func:`negotiate`, so a foreign wire profile still refuses regardless
     of how few heads a tenant declares.
     """

@@ -4,7 +4,7 @@
 model weights into a :class:`CompressionPlan` — a jit-like executable object
 owning one request's coding configuration end to end:
 
-    plan.encode(z)            -> WireBlob         (quantize/tile/entropy-code)
+    plan.encode(z)            -> WireBlob         (quantize/entropy-code)
     plan.decode_batch(blobs)  -> DecodedBatch     (vectorized host decode)
     plan.restore(decoded)     -> z_tilde          (jitted BaF restore)
 
@@ -14,10 +14,9 @@ device-side restore reuses one jitted trace per distinct
 (serve/batcher.py) never re-trace, no matter how many plans they hold.
 
 ``decode_batch`` is the batched/vectorized host decode path: N same-bucket
-wire blobs are parsed once, their payloads coalesced through the backend's
-vectorized batch decoder (core/codec.py ``decode_many``), and the channel
-untiling runs as one numpy pass over the whole stack instead of one
-jnp dispatch per request. Outputs are bit-identical to per-request decode.
+wire blobs are parsed once and the rANS chunks of all their payloads share
+one interleaved decode loop (core/codec.py ``decode_many``). Outputs are
+bit-identical to per-request decode.
 """
 from __future__ import annotations
 
@@ -30,7 +29,6 @@ import numpy as np
 from repro.core import codec as wire
 from repro.core.quant import compute_quant_params, quantize
 from repro.core.split import (SplitStats, restore_codes, restore_codes_fused)
-from repro.core.tiling import tile_batch, tile_grid
 from repro.obs import hooks
 from repro.pipeline.op import OperatingPoint
 
@@ -102,31 +100,17 @@ class DecodedBatch:
                             maxs=rep(self.maxs, reps, axis=0))
 
 
-def _untile_np(tiles: np.ndarray, c: int) -> np.ndarray:
-    """(M, rows*H, cols*W) tiled images -> (M, H, W, C), pure numpy.
-
-    Vectorized over the whole stack — the host-side inverse of
-    core/tiling.py's ``tile_channels`` without a per-request jnp dispatch.
-    """
-    rows, cols = tile_grid(c)
-    m, th, tw = tiles.shape
-    h, w = th // rows, tw // cols
-    y = tiles.reshape(m, rows, h, cols, w)
-    y = y.transpose(0, 1, 3, 2, 4).reshape(m, c, h, w)
-    return np.ascontiguousarray(y.transpose(0, 2, 3, 1))
-
-
 class CompressionPlan:
     """Executable coding pipeline for one operating point.
 
     Build via :func:`compile` (cached), not directly. The plan owns the
-    resolved operating point; every stage reads configuration from it, so
-    there is no loose ``(C, bits, backend)`` plumbing between stages.
+    operating point; every stage reads configuration from it, so there is
+    no loose ``(C, bits)`` plumbing between stages.
     """
 
     def __init__(self, op: OperatingPoint, spec: ModelSpec, *,
                  fused: bool = True, consolidation: bool = True):
-        self.op = op.resolve()
+        self.op = op
         self.spec = spec
         self.fused = fused
         self.consolidation = consolidation
@@ -136,9 +120,6 @@ class CompressionPlan:
                 f"operating point transmits C={self.op.c} channels but the "
                 f"model spec selects {sel.shape[0]}")
         self._sel = jnp.asarray(sel, jnp.int32)
-        # resolve the backend now: a typo'd backend fails at compile time,
-        # not on the first request
-        wire.backend_wants_tiling(self.op.wire_backend)
 
     # -- keys ---------------------------------------------------------------
     @property
@@ -173,25 +154,17 @@ class CompressionPlan:
 
     def encode_codes(self, codes: np.ndarray, qp,
                      raw_bits: int | None = None) -> WireBlob:
-        """Tile + entropy-code an already-quantized code tensor (B, H, W, C).
+        """Entropy-code an already-quantized code tensor (B, H, W, C).
 
         The coding half of :meth:`encode`, exposed so stateful callers can
         feed *derived* code tensors — the streaming session codec codes the
         temporal delta of two frames' codes through exactly this path, so
-        P-frames ride the same backends, container format, and wire
+        P-frames ride the same coder, container format, and wire
         accounting as I-frames. ``qp`` carries the side info serialized with
         the stream (the current frame's quant params, not the reference's).
         """
-        with hooks.timed("pipeline.encode", backend=self.op.wire_backend):
-            if self.op.tiling == "tiled":
-                # image-style codecs get the paper's tiled 2D image, one per
-                # batch element, stacked vertically
-                tiled = np.asarray(tile_batch(jnp.asarray(codes)))
-                stream = tiled.reshape(-1, tiled.shape[-1])
-            else:
-                # direct backends (rANS) code the channel-last tensor as-is
-                stream = codes
-            enc = wire.encode(stream, qp, backend=self.op.wire_backend)
+        with hooks.timed("pipeline.encode"):
+            enc = wire.encode(codes, qp)
             if raw_bits is None:
                 raw_bits = int(np.prod(codes.shape)) * 32
             stats = SplitStats(
@@ -206,7 +179,7 @@ class CompressionPlan:
                             shape=tuple(codes.shape), stats=stats)
 
     def encode(self, z) -> WireBlob:
-        """Quantize/tile/entropy-code the split activation ``z`` (B, H, W, P)
+        """Quantize/entropy-code the split activation ``z`` (B, H, W, P)
         and serialize the container; returns the blob with wire accounting."""
         codes, qp = self._quantize(z)
         return self.encode_codes(codes, qp,
@@ -214,10 +187,10 @@ class CompressionPlan:
 
     # -- decode (cloud side, host) ------------------------------------------
     def _check_blob(self, blob: WireBlob, shape: tuple) -> None:
-        if blob.op.resolve() != self.op:
+        if blob.op != self.op:
             raise ValueError(
-                f"blob was encoded at {blob.op.resolve()}, this plan "
-                f"executes {self.op}")
+                f"blob was encoded at {blob.op}, this plan executes "
+                f"{self.op}")
         if tuple(blob.shape) != shape:
             raise ValueError(
                 f"mixed shapes in one decode batch: {blob.shape} vs {shape}")
@@ -230,15 +203,13 @@ class CompressionPlan:
         """Vectorized host decode across N same-bucket requests.
 
         All blobs must share this plan's operating point and one codes shape
-        (the micro-batcher's bucket invariant). Payload entropy-decode is
-        coalesced by the backend's batch decoder where registered and the
-        untiling runs once over the whole stack; output rows are bit-exact
+        (the micro-batcher's bucket invariant). The entropy decode of all
+        payloads shares one interleaved rANS loop; output rows are bit-exact
         with per-request decode, in input order.
         """
         if not blobs:
             raise ValueError("decode_batch needs at least one blob")
-        with hooks.timed("pipeline.decode_batch",
-                         backend=self.op.wire_backend):
+        with hooks.timed("pipeline.decode_batch"):
             shape = tuple(blobs[0].shape)
             for blob in blobs:
                 self._check_blob(blob, shape)
@@ -246,12 +217,7 @@ class CompressionPlan:
             streams, qps = wire.decode_many(encs)
             n = len(blobs)
             b, h, w, c = shape
-            if self.op.tiling == "tiled":
-                rows, cols = tile_grid(c)
-                codes = _untile_np(
-                    streams.reshape(n * b, rows * h, cols * w), c)
-            else:
-                codes = streams.reshape(n * b, h, w, c)
+            codes = streams.reshape(n * b, h, w, c)
             mins = np.stack([np.asarray(qp.mins, np.float16) for qp in qps])
             maxs = np.stack([np.asarray(qp.maxs, np.float16) for qp in qps])
             return DecodedBatch(codes=codes,
@@ -294,24 +260,6 @@ class CompressionPlan:
                 f"consolidation={self.consolidation})")
 
 
-def blob_from_tensor(enc: wire.EncodedTensor, op: OperatingPoint,
-                     batch: int) -> WireBlob:
-    """Wrap a parsed wire tensor as a plan blob (legacy-entry-point bridge).
-
-    The container's ``shape`` field stores the coded *stream* shape — the
-    tiled 2D image for image-style backends — so the codes shape is
-    reconstructed from the operating point's tiling grid.
-    """
-    rop = op.resolve()
-    if rop.tiling == "tiled":
-        rows, cols = tile_grid(rop.c)
-        th, tw = enc.shape
-        shape = (batch, th // (batch * rows), tw // cols, rop.c)
-    else:
-        shape = tuple(enc.shape)
-    return WireBlob(data=enc.to_bytes(), op=rop, shape=shape)
-
-
 def compile(op: OperatingPoint, model_spec: ModelSpec, *,   # noqa: A001
             fused: bool = True,
             consolidation: bool = True) -> CompressionPlan:
@@ -323,9 +271,6 @@ def compile(op: OperatingPoint, model_spec: ModelSpec, *,   # noqa: A001
     ``(C, bits, bucket)``, so even a fresh plan object re-traces nothing
     the process has already compiled.
     """
-    # key on the *resolved* point: an auto-field op on the encode side and
-    # its resolved twin from a decoded blob must share one cached plan
-    op = op.resolve()
     key = (op, fused, consolidation)
     plan = model_spec._plans.get(key)
     if plan is None:

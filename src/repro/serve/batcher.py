@@ -54,9 +54,8 @@ class BucketKey:
 
 @dataclass(frozen=True)
 class PlanBucketKey:
-    """Bucket key of the plan-API path: the full operating point (backend,
-    tiling, context included — mixed backends must never share one batched
-    decode) plus the spatial shape."""
+    """Bucket key of the plan-API path: the full operating point (a batched
+    decode runs one plan) plus the spatial shape."""
     op: Any                    # repro.pipeline.OperatingPoint
     h: int
     w: int

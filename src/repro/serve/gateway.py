@@ -12,15 +12,16 @@ service loop over many concurrent requests (paper Fig. 1 at serving scale):
 All coding state flows through :mod:`repro.pipeline`: the rate controller
 hands back an :class:`OperatingPoint`, the gateway compiles (cached) one
 :class:`CompressionPlan` per point against its per-C model specs, and every
-stage reads configuration from the plan — no loose ``(C, bits, backend)``
-tuples.
+stage reads configuration from the plan — no loose ``(C, bits)`` tuples.
+The entropy coder is static rANS on the channel-last tensor (repro.codec),
+the only one the wire speaks.
 
 Design points:
   * the rate controller (serve/rate_control.py) consults the channel's
     remaining bit budget per request, so operating points adapt to congestion;
-  * ``capabilities`` (repro.pipeline.Capabilities) lets a gateway refuse — or
-    downgrade — operating points whose wire profile or backend it does not
-    speak, *before* any bytes are encoded;
+  * ``capabilities`` (repro.pipeline.Capabilities) lets a gateway refuse —
+    or downgrade — operating points whose wire profile or bit depth it does
+    not speak, *before* any bytes are encoded;
   * each C has its own BaF predictor (its input width is C) — the gateway
     holds a bank ``{c: (baf_params, sel_idx)}`` compiled into per-C
     ``ModelSpec``s;
@@ -86,9 +87,6 @@ class ServingGateway:
     channel : SimulatedChannel or None (None = ideal wire, zero latency)
     controller : RateController or None (None = fixed ``default_op``)
     default_op : operating point used when no controller is given
-    backend : legacy override — when set, every selected operating point is
-              re-based onto this entropy backend (None = respect the point's
-              own backend, the plan-API default)
     capabilities : what this gateway speaks; selected operating points are
               negotiated against it (refuse or downgrade) before encoding
     max_batch : micro-batch cap (1 = naive one-at-a-time serving)
@@ -108,8 +106,7 @@ class ServingGateway:
                  channel: SimulatedChannel | None = None,
                  controller: RateController | None = None,
                  default_op: OperatingPoint | None = None,
-                 backend: str | None = None, max_batch: int = 8,
-                 fused: bool = True,
+                 max_batch: int = 8, fused: bool = True,
                  capabilities: Capabilities | None = None,
                  executor: CloudExecutor | None = None,
                  shared_executor: bool = False,
@@ -123,7 +120,6 @@ class ServingGateway:
                        for c, (p, s) in self.baf_bank.items()}
         self.channel = channel
         self.controller = controller
-        self.backend = backend
         self.capabilities = capabilities
         if default_op is None:
             c = max(self.baf_bank)
@@ -169,10 +165,8 @@ class ServingGateway:
 
     # -- plans --------------------------------------------------------------
     def _fit_op(self, op: OperatingPoint) -> OperatingPoint:
-        """Re-base onto the legacy backend override, negotiate against the
-        gateway's capabilities, and check the BaF bank covers the C."""
-        if self.backend is not None and op.backend != self.backend:
-            op = op.with_backend(self.backend)
+        """Negotiate against the gateway's capabilities and check the BaF
+        bank covers the C."""
         op = negotiate(op, self.capabilities)
         if op.c not in self.baf_bank:
             raise ValueError(f"operating point picked C={op.c} with no BaF "
@@ -465,8 +459,7 @@ class MultiTenantGateway(ServingGateway):
                  channels: dict[str, SimulatedChannel] | None = None,
                  controller: RateController | None = None,
                  default_op: OperatingPoint | None = None,
-                 backend: str | None = None, max_batch: int = 8,
-                 fused: bool = True,
+                 max_batch: int = 8, fused: bool = True,
                  capabilities: Capabilities | None = None,
                  budget_bits_per_tick: int | None = None,
                  tick_s: float = 1.0, quantum_bits: int | None = None,
@@ -478,8 +471,8 @@ class MultiTenantGateway(ServingGateway):
                  admission: AdmissionPolicy | None = None,
                  tracer=None, metrics=None):
         super().__init__(params, baf_bank, channel=None, controller=None,
-                         default_op=default_op, backend=backend,
-                         max_batch=max_batch, fused=fused,
+                         default_op=default_op, max_batch=max_batch,
+                         fused=fused,
                          capabilities=capabilities, executor=executor,
                          shared_executor=shared_executor,
                          tracer=tracer, metrics=metrics)

@@ -27,9 +27,8 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
-# OperatingPoint is owned by the pipeline package now (it grew backend /
-# tiling / context / wire-profile fields); re-exported here so serve-side
-# callers keep importing it from repro.serve.
+# OperatingPoint is owned by the pipeline package; re-exported here so
+# serve-side callers keep importing it from repro.serve.
 from repro.pipeline import OperatingPoint
 
 
@@ -179,18 +178,16 @@ def session_bits_per_frame(point: RDPoint, *, keyframe_interval: int,
     return per_frame / frame_stride
 
 
-def rd_grid(baf_bank: dict, bits_sweep=(2, 4, 6, 8),
-            backend: str = "zlib") -> list[OperatingPoint]:
-    """The default calibration grid: every bank C crossed with the bit sweep
-    on one backend. This list is also the RD cache's identity — see
+def rd_grid(baf_bank: dict, bits_sweep=(2, 4, 6, 8)) -> list[OperatingPoint]:
+    """The default calibration grid: every bank C crossed with the bit
+    sweep. This list is also the RD cache's identity — see
     :func:`load_or_build_rd_table`."""
-    return [OperatingPoint(c=c, bits=bits, backend=backend)
+    return [OperatingPoint(c=c, bits=bits)
             for c in sorted(baf_bank) for bits in bits_sweep]
 
 
 def build_rd_table(params, baf_bank: dict, imgs, *,
-                   bits_sweep=(2, 4, 6, 8), backend: str = "zlib",
-                   consolidation: bool = True,
+                   bits_sweep=(2, 4, 6, 8), consolidation: bool = True,
                    ops: "list[OperatingPoint] | None" = None) -> list[RDPoint]:
     """Offline operating-point sweep with the repo's own fidelity metrics.
 
@@ -199,7 +196,7 @@ def build_rd_table(params, baf_bank: dict, imgs, *,
                (the BaF net's input width is C, so each C needs its own)
     imgs     : (B, H, W, 3) calibration batch the costs/metrics are measured on
     ops      : explicit grid of operating points; default
-               ``rd_grid(baf_bank, bits_sweep, backend)``
+               ``rd_grid(baf_bank, bits_sweep)``
 
     Each point's wire cost is measured by compiling its
     :class:`repro.pipeline.CompressionPlan` and encoding every calibration
@@ -210,7 +207,7 @@ def build_rd_table(params, baf_bank: dict, imgs, *,
     from repro.models.cnn import cnn_edge
 
     if ops is None:
-        ops = rd_grid(baf_bank, bits_sweep, backend)
+        ops = rd_grid(baf_bank, bits_sweep)
     edge = jax.jit(lambda p, i: cnn_edge(p, i)[1])
     z = edge(params, imgs)
     specs, anchors = {}, {}
@@ -252,16 +249,15 @@ def build_rd_table(params, baf_bank: dict, imgs, *,
 
 def op_to_json(op: OperatingPoint) -> dict:
     return {"c": op.c, "bits": op.bits, "backend": op.backend,
-            "tiling": op.tiling, "context": op.context,
             "profile": op.profile}
 
 
 def op_from_json(r: dict) -> OperatingPoint:
+    """Inverse of :func:`op_to_json`; a row of a retired backend raises
+    ``ValueError``, which the RD cache treats as stale."""
     from repro.pipeline import WIRE_PROFILE_VERSION
     return OperatingPoint(c=int(r["c"]), bits=int(r["bits"]),
-                          backend=str(r.get("backend", "zlib")),
-                          tiling=str(r.get("tiling", "auto")),
-                          context=str(r.get("context", "auto")),
+                          backend=str(r["backend"]),
                           profile=int(r.get("profile",
                                             WIRE_PROFILE_VERSION)))
 
@@ -300,15 +296,15 @@ def load_or_build_rd_table(cache_path, key: dict | None = None, build=None, *,
                            ops: "list[OperatingPoint] | None" = None,
                            tasks: dict | None = None) -> list[RDPoint]:
     """RD sweeps re-encode every calibration example at every operating
-    point — too slow to redo per CI run now that the rANS backends are in
-    the sweep. Cache the table to disk keyed by the sweep's identity.
+    point — too slow to redo per CI run with the rANS coder in the sweep.
+    Cache the table to disk keyed by the sweep's identity.
 
     The effective cache key is ``key`` (caller-provided sweep inputs such as
     the calibration seed/shape) augmented with:
 
       * the full ``ops`` grid (every field of every operating point) when
-        given — a sweep over different backends, bit depths, tilings, or
-        wire profiles can never alias a cached table,
+        given — a sweep over different channel counts, bit depths, or wire
+        profiles can never alias a cached table,
       * :func:`codec_revision` — container-format changes invalidate every
         cached table automatically (pre-plan caches keyed on backend+seed
         only are treated as stale and rebuilt in place), and

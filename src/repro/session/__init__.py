@@ -8,7 +8,7 @@ it:
   * :mod:`repro.session.codec` — per-session reference state and the
     SessionFrame wire format: I-frames are today's ``CompressionPlan.encode``
     containers unchanged; P-frames code the temporal delta of quantized codes
-    through the same entropy backends, wrapped in a versioned, CRC-hardened
+    through the same rANS coder, wrapped in a versioned, CRC-hardened
     frame header (session id, frame seq, reference seq, I/P flag).
   * :mod:`repro.session.recovery` — the desync/NACK/intra-refresh state
     machine: a lost or corrupt frame can never be silently restored; the

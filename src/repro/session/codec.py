@@ -16,7 +16,7 @@ serving. A **P-frame**'s payload is the same container format over the
 
     delta = (codes_t - codes_ref) mod 2^bits
 
-entropy-coded by the plan's backend (rANS static tables adapt to the
+entropy-coded by the plan's rANS coder (its static tables adapt to the
 delta's near-zero concentration, which is where the P-frame bit savings
 come from). Reconstruction inverts the delta exactly, so a P-frame decodes
 to bit-identical codes as the I-frame it chains from — temporal prediction
@@ -283,10 +283,11 @@ class SessionDecoder:
         op = self.cfg.levels[frame.level]
         plan = self.plan_for(op)
         from repro.core.codec import EncodedTensor
-        from repro.pipeline import blob_from_tensor
+        from repro.pipeline import WireBlob
         try:
             enc = EncodedTensor.from_bytes(frame.payload)
-            decoded = plan.decode(blob_from_tensor(enc, plan.op, 1))
+            decoded = plan.decode(WireBlob(data=frame.payload, op=plan.op,
+                                           shape=enc.shape))
         except (ValueError, CorruptStream) as e:
             # the payload CRC passed, so this is a malformed-but-intact
             # container (encoder bug or a forged CRC); surface it as

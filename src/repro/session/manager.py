@@ -254,9 +254,9 @@ class SessionManager:
         skipped (never guessed at).
         """
         from repro.serve.rate_control import session_bits_per_frame
-        by_op = {p.op.resolve(): p for p in rd_table}
+        by_op = {p.op: p for p in rd_table}
         for i, rung in enumerate(self.ladder):
-            point = by_op.get(self._levels[i].resolve())
+            point = by_op.get(self._levels[i])
             if point is None:
                 continue
             cost = session_bits_per_frame(

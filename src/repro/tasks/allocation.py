@@ -55,7 +55,7 @@ class AllocationDecision:
 
 
 def _op_sort_key(op) -> tuple:
-    return (op.c, op.bits, op.backend, op.tiling, op.context, op.profile)
+    return (op.c, op.bits, op.profile)
 
 
 class BitAllocationController:

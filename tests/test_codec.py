@@ -1,4 +1,5 @@
-"""Wire codec: n-bit packing, entropy coding, paper-style bit accounting."""
+"""Wire codec: the BaF2 header around rANS containers, paper-style bit
+accounting, and the refusal of retired backend ids."""
 import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
@@ -12,27 +13,11 @@ def _qp(c, bits, rng):
     return QuantParams(mins=mins, maxs=(mins + 1).astype(np.float16), bits=bits)
 
 
-@given(bits=st.integers(2, 8), n=st.integers(1, 300), seed=st.integers(0, 2**16))
-@settings(max_examples=40, deadline=None)
-def test_property_pack_unpack_roundtrip(bits, n, seed):
-    r = np.random.default_rng(seed)
-    codes = r.integers(0, 1 << bits, size=n).astype(np.uint8)
-    assert np.array_equal(wire.unpack_bits(wire.pack_bits(codes, bits), bits, n),
-                          codes)
-
-
-def test_packed_size_is_exact():
-    codes = np.zeros(100, np.uint8)
-    for bits in range(2, 9):
-        assert len(wire.pack_bits(codes, bits)) == (100 * bits + 7) // 8
-
-
-@pytest.mark.parametrize("backend", ["zlib", "raw"])
 @pytest.mark.parametrize("bits", [2, 5, 8])
-def test_encode_decode_roundtrip(rng, backend, bits):
+def test_encode_decode_roundtrip(rng, bits):
     codes = rng.integers(0, 1 << bits, size=(6, 6, 8)).astype(np.uint8)
     qp = _qp(8, bits, rng)
-    enc = wire.encode(codes, qp, backend=backend)
+    enc = wire.encode(codes, qp)
     blob = enc.to_bytes()
     dec_codes, dec_qp = wire.decode(wire.EncodedTensor.from_bytes(blob))
     assert np.array_equal(dec_codes, codes)
@@ -43,18 +28,9 @@ def test_encode_decode_roundtrip(rng, backend, bits):
 def test_side_info_accounting(rng):
     codes = rng.integers(0, 256, size=(4, 4, 16)).astype(np.uint8)
     qp = _qp(16, 8, rng)
-    enc = wire.encode(codes, qp, backend="raw")
+    enc = wire.encode(codes, qp)
     # paper: C*32 bits of fp16 min/max side info + payload
     assert enc.total_bits() == 8 * len(enc.payload) + 16 * 32
-
-
-def test_zlib_beats_raw_on_structured_data(rng):
-    # low-entropy stream (mostly zeros) must compress
-    codes = (rng.random(size=(64, 64)) < 0.05).astype(np.uint8) * 7
-    qp = _qp(1, 8, rng)
-    z = wire.encode(codes, qp, backend="zlib")
-    raw = wire.encode(codes, qp, backend="raw")
-    assert len(z.payload) < 0.5 * len(raw.payload)
 
 
 def test_entropy_floor_below_payload(rng):
@@ -67,13 +43,13 @@ def test_entropy_floor_below_payload(rng):
 @given(seed=st.integers(0, 2**16))
 @settings(max_examples=10, deadline=None)
 def test_property_entropy_is_compression_lower_bound_ish(seed):
-    """DEFLATE payload should be within ~2x of the order-0 entropy floor for
-    iid streams (sanity on the accounting, not a codec guarantee)."""
+    """The rANS payload cannot beat half the order-0 entropy floor on iid
+    streams (sanity on the accounting, not a codec guarantee)."""
     r = np.random.default_rng(seed)
     codes = r.integers(0, 4, size=4096).astype(np.uint8)
     qp = QuantParams(mins=np.zeros(1, np.float16), maxs=np.ones(1, np.float16),
                      bits=2)
-    enc = wire.encode(codes, qp, backend="zlib")
+    enc = wire.encode(codes, qp)
     h = wire.empirical_entropy_bits(codes, 2)
     assert 8 * len(enc.payload) >= 0.5 * h
 
@@ -82,22 +58,20 @@ def test_property_entropy_is_compression_lower_bound_ish(seed):
 # Hardening + header integrity (serving-gateway PR satellites)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["zlib", "raw"])
 @pytest.mark.parametrize("bits", [3, 5, 6])
-def test_roundtrip_odd_bit_widths(rng, backend, bits):
+def test_roundtrip_odd_bit_widths(rng, bits):
     codes = rng.integers(0, 1 << bits, size=(5, 7, 4)).astype(np.uint8)
     qp = _qp(4, bits, rng)
     dec, dec_qp = wire.decode(wire.EncodedTensor.from_bytes(
-        wire.encode(codes, qp, backend=backend).to_bytes()))
+        wire.encode(codes, qp).to_bytes()))
     assert np.array_equal(dec, codes)
     assert dec_qp.bits == bits
 
 
-@pytest.mark.parametrize("backend", ["zlib", "raw"])
-def test_roundtrip_single_element(rng, backend):
+def test_roundtrip_single_element(rng):
     codes = np.asarray([[3]], np.uint8)
     qp = _qp(1, 4, rng)
-    dec, _ = wire.decode(wire.encode(codes, qp, backend=backend))
+    dec, _ = wire.decode(wire.encode(codes, qp))
     assert dec.shape == (1, 1) and dec[0, 0] == 3
 
 
@@ -105,53 +79,27 @@ def test_header_integrity_multidim(rng):
     shape = (2, 3, 4, 5)
     codes = rng.integers(0, 64, size=shape).astype(np.uint8)
     qp = _qp(5, 6, rng)
-    enc = wire.encode(codes, qp, backend="raw")
-    enc2 = wire.EncodedTensor.from_bytes(enc.to_bytes())
+    enc = wire.encode(codes, qp)
+    blob = enc.to_bytes()
+    assert blob[4] == wire.RANS_WIRE_ID
+    enc2 = wire.EncodedTensor.from_bytes(blob)
     assert enc2.shape == shape
-    assert enc2.bits == 6 and enc2.backend == "raw"
+    assert enc2.bits == 6
     assert enc2.side_info == enc.side_info
     assert enc2.payload == enc.payload
     dec, _ = wire.decode(enc2)
     assert dec.shape == shape and np.array_equal(dec, codes)
 
 
-@pytest.mark.parametrize("bits", [4, 8, 16])
-def test_unpack_bits_rejects_short_stream(bits):
-    codes = np.arange(16, dtype=np.uint16) % (1 << min(bits, 8))
-    data = wire.pack_bits(codes, bits)
-    with pytest.raises(ValueError, match="too short"):
-        wire.unpack_bits(data[:-1], bits, 16)
-
-
-def test_png_rejects_negative_codes(rng):
-    qp = _qp(4, 8, rng)
-    with pytest.raises(ValueError, match="negative"):
-        wire.encode(np.full((4, 4), -1, np.int32), qp, backend="png")
-
-
-def test_png_rejects_codes_over_8_bits(rng):
-    qp = _qp(4, 8, rng)
-    with pytest.raises(ValueError, match="fit in"):
-        wire.encode(np.full((4, 4), 300, np.int32), qp, backend="png")
-
-
-def test_png_roundtrip_still_works(rng):
-    codes = rng.integers(0, 256, size=(8, 8)).astype(np.uint8)
-    qp = _qp(8, 8, rng)
-    enc = wire.encode(codes, qp, backend="png")
-    dec, _ = wire.decode(wire.EncodedTensor.from_bytes(enc.to_bytes()))
-    assert np.array_equal(dec.reshape(8, 8), codes)
-
-
 # ---------------------------------------------------------------------------
-# from_bytes structural hardening (entropy-coding PR satellite): every
-# malformation fails loudly at the header with its own message, instead of
-# surfacing later as a short stream inside unpack_bits
+# from_bytes structural hardening: every malformation fails loudly at the
+# header with its own message, instead of surfacing later inside the rANS
+# container
 # ---------------------------------------------------------------------------
 
-def _blob(rng, backend="raw"):
+def _blob(rng):
     codes = rng.integers(0, 64, size=(4, 6)).astype(np.uint8)
-    return wire.encode(codes, _qp(6, 6, rng), backend=backend).to_bytes()
+    return wire.encode(codes, _qp(6, 6, rng)).to_bytes()
 
 
 def test_from_bytes_rejects_bad_magic(rng):
@@ -193,17 +141,11 @@ def test_from_bytes_rejects_trailing_garbage(rng):
         wire.EncodedTensor.from_bytes(blob + b"\x00")
 
 
-def test_from_bytes_rejects_unknown_backend_id(rng):
+# 0, 1, 2 and 4 were the retired zlib, png, raw and adaptive-context coders
+@pytest.mark.parametrize("backend_id", [250, 0, 1, 2, 4])
+def test_from_bytes_rejects_unknown_backend_id(rng, backend_id):
     blob = bytearray(_blob(rng))
-    blob[4] = 250
+    blob[4] = backend_id
     with pytest.raises(ValueError, match="unknown backend id"):
         wire.EncodedTensor.from_bytes(bytes(blob))
 
-
-def test_backend_registry_lists_rans(rng):
-    names = wire.backend_names()
-    for name in ("raw", "zlib", "png", "rans", "rans-ctx"):
-        assert name in names
-    with pytest.raises(ValueError, match="unknown backend"):
-        wire.encode(np.zeros((2, 2), np.uint8), _qp(2, 8, rng),
-                    backend="flif")

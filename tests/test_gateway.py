@@ -7,6 +7,7 @@ from repro.configs.yolo_baf import smoke_config, smoke_data_config
 from repro.core.baf import BaFConvConfig, init_baf_conv
 from repro.data.synthetic import shapes_batch_iterator
 from repro.models.cnn import init_cnn
+from repro.pipeline import DecodedBatch
 from repro.serve import (Capabilities, ChannelConfig, DecodedRequest,
                          MicroBatcher, MultiTenantGateway, NegotiationError,
                          OperatingPoint, RateController, RDPoint,
@@ -348,38 +349,42 @@ def test_gateway_telemetry_accounts_wire_and_queue(tiny_bank):
 
 
 # ---------------------------------------------------------------------------
-# Entropy-coded serving (rANS backends) + true-byte accounting
+# Entropy-coded serving (rANS) + true-byte accounting
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["rans", "rans-ctx"])
-def test_gateway_rans_backend_matches_zlib_logits(tiny_bank, backend):
-    """The entropy coder is lossless: logits must be identical across
-    backends at the same operating point."""
+@pytest.mark.parametrize("bucket", [1, 2, 4, 8])
+def test_gateway_rans_backend_matches_zlib_logits(tiny_bank, bucket):
+    """The entropy coder is lossless: served logits equal the uncoded path
+    (the plan's quantizer, then restore and the cloud forward, with no
+    bytes in between) at every micro-batch bucket."""
     params, bank, imgs = tiny_bank
     op = OperatingPoint(c=8, bits=8)
-    ref = ServingGateway(params, bank, default_op=op, max_batch=4,
-                         backend="zlib")
-    gw = ServingGateway(params, bank, default_op=op, max_batch=4,
-                        backend=backend)
-    r_ref, _ = ref.serve(imgs[:4])
-    r_gw, _ = gw.serve(imgs[:4])
-    for a, b in zip(r_gw, r_ref):
-        np.testing.assert_allclose(a.logits, b.logits, atol=1e-5, rtol=1e-5)
+    gw = ServingGateway(params, bank, default_op=op, max_batch=bucket)
+    responses, tel = gw.serve(imgs[:bucket])
+    assert [r.padded_size for r in tel.records] == [bucket] * bucket
+    plan = gw.plan_for(op)
+    refs = [plan.quantize(gw._edge_fn(params, imgs[i:i + 1]))
+            for i in range(bucket)]
+    uncoded = DecodedBatch(*(np.concatenate([r[k] for r in refs])
+                             for k in range(3)))
+    logits = np.asarray(gw._cloud_fn(params, plan.restore(uncoded)))
+    for i, r in enumerate(responses):
+        np.testing.assert_allclose(r.logits, logits[i], atol=1e-5,
+                                   rtol=1e-5)
 
 
 def test_gateway_downgrades_unsupported_backend(tiny_bank):
-    """A gateway that only speaks zlib re-bases a rans operating point onto
-    zlib at negotiation time — before any bytes are encoded."""
+    """A gateway that decodes at most 6 bits re-bases an 8-bit operating
+    point onto 6 bits at negotiation time — before any bytes are encoded."""
     params, bank, imgs = tiny_bank
     gw = ServingGateway(
-        params, bank,
-        default_op=OperatingPoint(c=8, bits=8, backend="rans"),
-        capabilities=Capabilities(backends=("zlib",)), max_batch=2)
+        params, bank, default_op=OperatingPoint(c=8, bits=8),
+        capabilities=Capabilities(max_bits=6), max_batch=2)
     responses, _ = gw.serve(imgs[:2])
-    assert responses[0].op.wire_backend == "zlib"
-    # and the served logits still match an all-zlib gateway bit-for-bit
+    assert responses[0].op == OperatingPoint(c=8, bits=6)
+    # and the served logits match a gateway built at 6 bits
     ref = ServingGateway(params, bank,
-                         default_op=OperatingPoint(c=8, bits=8), max_batch=2)
+                         default_op=OperatingPoint(c=8, bits=6), max_batch=2)
     r_ref, _ = ref.serve(imgs[:2])
     for a, b in zip(responses, r_ref):
         np.testing.assert_allclose(a.logits, b.logits, atol=1e-5, rtol=1e-5)
@@ -389,9 +394,8 @@ def test_gateway_refuses_without_downgrade(tiny_bank):
     params, bank, imgs = tiny_bank
     with pytest.raises(NegotiationError):
         ServingGateway(
-            params, bank,
-            default_op=OperatingPoint(c=8, bits=8, backend="rans"),
-            capabilities=Capabilities(backends=("zlib",), downgrade=False))
+            params, bank, default_op=OperatingPoint(c=8, bits=8),
+            capabilities=Capabilities(max_bits=6, downgrade=False))
     with pytest.raises(NegotiationError, match="profile"):
         ServingGateway(params, bank,
                        default_op=OperatingPoint(c=8, bits=8, profile=1),
@@ -430,7 +434,7 @@ def test_gateway_meters_actual_container_bytes(tiny_bank):
     params, bank, imgs = tiny_bank
     ch = SimulatedChannel(ChannelConfig(bandwidth_bps=1e6))
     gw = ServingGateway(params, bank, default_op=OperatingPoint(c=8, bits=8),
-                        channel=ch, max_batch=4, backend="rans")
+                        channel=ch, max_batch=4)
     op, blob, stats, tx = gw.encode_request(imgs[:1], 0.0)
     assert tx.bits == 8 * blob.nbytes == stats.wire_bits
     assert stats.wire_bits > stats.total_bits      # header is on the wire too

@@ -64,7 +64,7 @@ def test_in_repo_serving_flows_emit_no_deprecation_warnings(tiny_system):
         # multi-tenant event loop with rans wire accounting
         mt = MultiTenantGateway(
             params, bank, tenants=[TenantSpec("a"), TenantSpec("b")],
-            default_op=OperatingPoint(c=8, bits=8), backend="rans",
+            default_op=OperatingPoint(c=8, bits=8),
             max_batch=4, batch_window_s=0.01, adaptive_window=True)
         mt.serve_tenants([
             TenantRequest("ab"[i % 2], imgs[i % len(imgs)], 0.001 * i)

@@ -1,4 +1,5 @@
-"""Entropy-coding subsystem: rANS core, context model, container, backends.
+"""Entropy-coding subsystem: rANS core, container, tensor coding, batched
+decode, wire integration.
 
 Round-trip properties run under hypothesis when installed (via the
 hypothesis_compat shim) and as seeded spot checks otherwise.
@@ -10,10 +11,8 @@ import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.codec import (CorruptStream, RansContainer, RansTable,
-                         decode_channels, decode_ctx, decode_tensor,
-                         encode_adaptive_tensor, encode_ctx,
-                         encode_static_tensor, normalize_freqs, plan_lanes,
-                         rans_decode)
+                         decode_channels, decode_tensor, encode_static_tensor,
+                         normalize_freqs, rans_decode)
 from repro.codec import container as box
 from repro.codec.batch import decode_tensor_batch
 from repro.codec.rans import (RANS_L, encode_static, encode_static_rows,
@@ -28,24 +27,6 @@ def _qp(c, bits, rng):
     mins = rng.normal(size=(c,)).astype(np.float16)
     return QuantParams(mins=mins, maxs=(mins + 1).astype(np.float16),
                        bits=bits)
-
-
-def _smooth_residuals(rng, shape, bits, rho=0.9):
-    """2D spatially correlated quantized field — synthetic BaF residual.
-
-    shape is (B, H, W, C); correlation runs along H (the up-neighbor the
-    rans-ctx model keys on) and W.
-    """
-    z = rng.normal(size=shape)
-    s = np.sqrt(1 - rho**2)
-    for i in range(1, shape[1]):
-        z[:, i] = rho * z[:, i - 1] + s * z[:, i]
-    for j in range(1, shape[2]):
-        z[:, :, j] = rho * z[:, :, j - 1] + s * z[:, :, j]
-    lo = z.min(axis=tuple(range(z.ndim - 1)), keepdims=True)
-    hi = z.max(axis=tuple(range(z.ndim - 1)), keepdims=True)
-    q = np.round((z - lo) / np.maximum(hi - lo, 1e-9) * ((1 << bits) - 1))
-    return np.clip(q, 0, (1 << bits) - 1).astype(np.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -96,20 +77,7 @@ def test_property_static_roundtrip_arbitrary_distributions(bits, n, lanes,
     assert np.array_equal(dec, syms)
 
 
-@given(bits=st.integers(1, 12), h=st.integers(1, 24), w=st.integers(1, 24),
-       seed=st.integers(0, 2**16))
-@settings(max_examples=40, deadline=None)
-def test_property_ctx_roundtrip(bits, h, w, seed):
-    r = np.random.default_rng(seed)
-    syms = r.integers(0, 1 << bits, size=h * w).astype(np.uint32)
-    lanes = plan_lanes(syms.size, w)
-    states, words = encode_ctx(syms, bits, lanes, w)
-    dec = decode_ctx(states, words, syms.size, bits, lanes, w)
-    assert np.array_equal(dec, syms)
-
-
-@pytest.mark.parametrize("encode_fn", [encode_static_tensor,
-                                       encode_adaptive_tensor])
+@pytest.mark.parametrize("encode_fn", [encode_static_tensor])
 @pytest.mark.parametrize("shape", [(1, 1), (1,), (3, 1, 1), (2, 5, 3, 4),
                                    (0, 4), (4, 0), (6, 6, 8)])
 def test_tensor_roundtrip_edge_shapes(rng, encode_fn, shape):
@@ -121,9 +89,9 @@ def test_tensor_roundtrip_edge_shapes(rng, encode_fn, shape):
 @pytest.mark.parametrize("bits", [1, 2, 3, 5, 7, 9, 11, 12])
 def test_odd_bit_widths_both_modes(rng, bits):
     codes = rng.integers(0, 1 << bits, size=(2, 7, 5, 3)).astype(np.uint32)
-    for fn in (encode_static_tensor, encode_adaptive_tensor):
-        assert np.array_equal(
-            decode_tensor(fn(codes, bits), codes.shape, bits), codes)
+    assert np.array_equal(
+        decode_tensor(encode_static_tensor(codes, bits), codes.shape, bits),
+        codes)
 
 
 # ---------------------------------------------------------------------------
@@ -184,8 +152,8 @@ def _check_rows(syms, tables, prob_bits, lanes):
         assert np.array_equal(
             rans_decode(states[i], words[i], k, tables[i], lanes), syms[i])
     blob = box.pack_container(
-        mode=box.MODE_STATIC, bits=_ROW_BITS, prob_bits=prob_bits,
-        lanes=lanes, neighbor_dist=0, tables=[t.freqs for t in tables],
+        bits=_ROW_BITS, prob_bits=prob_bits,
+        lanes=lanes, tables=[t.freqs for t in tables],
         chunks=[(k, states[i], words[i]) for i in range(m)])
     # shape (K, M): chunk i is column i, so row i comes back as column i
     out = decode_tensor_batch([blob, blob], (k, m), _ROW_BITS)
@@ -325,8 +293,8 @@ def _batch_container(tables, k, lanes, bits, r):
         row[pos] = r.choice(rare, size=pos.size)
     states, words = encode_static_rows(syms, tables, lanes)
     blob = box.pack_container(
-        mode=box.MODE_STATIC, bits=bits, prob_bits=tables[0].prob_bits,
-        lanes=lanes, neighbor_dist=0, tables=[t.freqs for t in tables],
+        bits=bits, prob_bits=tables[0].prob_bits,
+        lanes=lanes, tables=[t.freqs for t in tables],
         chunks=[(k, states[i], words[i]) for i in range(len(tables))])
     return blob, syms
 
@@ -396,8 +364,8 @@ def _corrupt_static(kind, r):
         last[-1] ^= 1
         words[1] = last.tobytes()
     blobs[1] = box.pack_container(
-        mode=box.MODE_STATIC, bits=bits, prob_bits=prob_bits, lanes=lanes,
-        neighbor_dist=0, tables=freqs,
+        bits=bits, prob_bits=prob_bits, lanes=lanes,
+        tables=freqs,
         chunks=[(k, states[i], words[i]) for i in range(2)])
     return blobs, (1, k, 2), bits
 
@@ -422,7 +390,7 @@ def test_batched_decode_rejects_prob_bits_beyond_format():
     # sums right and has no zero, but no encoder writes prob_bits 16, and
     # its slot tables would take 2^16 entries per row
     blob = box.pack_container(
-        mode=box.MODE_STATIC, bits=1, prob_bits=16, lanes=1, neighbor_dist=0,
+        bits=1, prob_bits=16, lanes=1,
         tables=[np.array([1 << 15, 1 << 15])],
         chunks=[(1, np.array([RANS_L], "<u4"), b"")])
     with pytest.raises(CorruptStream, match="prob_bits 16 exceeds 15"):
@@ -445,21 +413,11 @@ def test_batched_decode_observes_rows_once(n, c):
     assert not hooks.enabled() and m.get("codec_decode_rows").count == 1
 
 
-def test_batched_decode_observes_nothing_for_ctx_containers(rng):
-    codes = rng.integers(0, 64, size=(1, 16, 16, 4)).astype(np.uint32)
-    blob = encode_adaptive_tensor(codes, 6)
-    m = MetricsRegistry()
-    with hooks.active(m):
-        out = decode_tensor_batch([blob, blob], codes.shape, 6)
-    assert np.array_equal(out[1], codes.ravel())
-    assert m.get("codec_decode_rows") is None
-
-
 def test_rans_rejects_out_of_range_codes(rng):
     with pytest.raises(ValueError, match="does not fit"):
         encode_static_tensor(np.full((4, 4), 300), 8)
     with pytest.raises(ValueError, match="negative"):
-        encode_adaptive_tensor(np.full((4, 4), -1), 8)
+        encode_static_tensor(np.full((4, 4), -1), 8)
     with pytest.raises(ValueError, match="1..12"):
         encode_static_tensor(np.zeros((4, 4), np.uint32), 16)
 
@@ -470,18 +428,17 @@ def test_rans_rejects_out_of_range_codes(rng):
 
 def test_partial_decode_matches_full(rng):
     codes = rng.integers(0, 256, size=(2, 8, 8, 6)).astype(np.uint32)
-    for fn in (encode_static_tensor, encode_adaptive_tensor):
-        blob = fn(codes, 8)
-        full = decode_tensor(blob, codes.shape, 8)
-        part = decode_channels(blob, [5, 0, 2])
-        for row, ch in zip(part, [5, 0, 2]):
-            assert np.array_equal(row, full[..., ch].reshape(-1))
+    blob = encode_static_tensor(codes, 8)
+    full = decode_tensor(blob, codes.shape, 8)
+    part = decode_channels(blob, [5, 0, 2])
+    for row, ch in zip(part, [5, 0, 2]):
+        assert np.array_equal(row, full[..., ch].reshape(-1))
 
 
 def test_partial_decode_skips_corrupt_other_chunks(rng):
     """Corruption in chunk j must not prevent decoding chunk i != j."""
     codes = rng.integers(0, 64, size=(1, 16, 16, 4)).astype(np.uint32)
-    blob = bytearray(encode_adaptive_tensor(codes, 6))
+    blob = bytearray(encode_static_tensor(codes, 6))
     blob[-3] ^= 0x55                       # flip bits inside the LAST chunk
     got = decode_channels(bytes(blob), [0])
     assert np.array_equal(got[0], codes[..., 0].reshape(-1))
@@ -504,12 +461,14 @@ def test_container_corruption_distinct_errors(rng, mutate, msg):
         RansContainer.parse(mutate(blob)).decode_all()
 
 
-def test_container_rejects_unknown_mode_with_valid_crc():
+# mode 1 was the retired adaptive-context coder
+@pytest.mark.parametrize("mode", [7, 1])
+def test_container_rejects_unknown_mode_with_valid_crc(mode):
     import struct
     import zlib as _z
 
     from repro.codec import container as box
-    hdr = box._HEADER.pack(box.MAGIC, box.VERSION, 7, 4, 12, 1, 0, 0, 0)
+    hdr = box._HEADER.pack(box.MAGIC, box.VERSION, mode, 4, 12, 1, 0, 0, 0)
     blob = hdr + struct.pack("<I", _z.crc32(hdr))
     with pytest.raises(CorruptStream, match="unknown container mode"):
         RansContainer.parse(blob)
@@ -535,8 +494,7 @@ def test_property_bit_flips_never_serve_wrong_data(seed):
     zlib metadata bits of the table blob) — wrong tensors are never served."""
     r = np.random.default_rng(seed)
     codes = r.integers(0, 256, size=(1, 8, 8, 3)).astype(np.uint32)
-    fn = encode_static_tensor if seed % 2 else encode_adaptive_tensor
-    blob = bytearray(fn(codes, 8))
+    blob = bytearray(encode_static_tensor(codes, 8))
     pos = int(r.integers(0, len(blob)))
     blob[pos] ^= 1 << int(r.integers(0, 8))
     try:
@@ -547,16 +505,15 @@ def test_property_bit_flips_never_serve_wrong_data(seed):
 
 
 # ---------------------------------------------------------------------------
-# wire-codec integration (core/codec.py registry)
+# wire-codec integration (core/codec.py)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["rans", "rans-ctx"])
-@pytest.mark.parametrize("bits", [2, 3, 5, 8])
-def test_wire_roundtrip_all_c_bits(rng, backend, bits):
+@pytest.mark.parametrize("bits", range(2, 9))
+def test_wire_roundtrip_all_c_bits(rng, bits):
     for c in (1, 4, 8):
         codes = rng.integers(0, 1 << bits, size=(2, 6, 6, c)).astype(np.uint8)
         qp = _qp(c, bits, rng)
-        enc = wire.encode(codes, qp, backend=backend)
+        enc = wire.encode(codes, qp)
         dec, dec_qp = wire.decode(
             wire.EncodedTensor.from_bytes(enc.to_bytes()))
         assert np.array_equal(dec, codes)
@@ -566,20 +523,9 @@ def test_wire_roundtrip_all_c_bits(rng, backend, bits):
 def test_wire_bits_counts_whole_container(rng):
     codes = rng.integers(0, 256, size=(1, 4, 4, 4)).astype(np.uint8)
     qp = _qp(4, 8, rng)
-    for backend in ("raw", "zlib", "rans", "rans-ctx"):
-        enc = wire.encode(codes, qp, backend=backend)
-        assert enc.wire_bits() == 8 * len(enc.to_bytes())
-        assert enc.total_bits() == enc.wire_bits() - 8 * enc.header_bytes()
-
-
-def test_ctx_beats_order0_floor_on_baf_residuals(rng):
-    """Acceptance: rans-ctx within 5% of the empirical-entropy floor on
-    synthetic BaF residuals (it lands well below by using 2D context)."""
-    codes = _smooth_residuals(rng, (2, 48, 48, 8), bits=6)
-    qp = _qp(8, 6, rng)
-    enc = wire.encode(codes, qp, backend="rans-ctx")
-    floor = wire.empirical_entropy_bits(codes, 6)
-    assert 8 * len(enc.payload) <= 1.05 * floor
+    enc = wire.encode(codes, qp)
+    assert enc.wire_bits() == 8 * len(enc.to_bytes())
+    assert enc.total_bits() == enc.wire_bits() - 8 * enc.header_bytes()
 
 
 def test_static_close_to_floor_on_skewed_stream(rng):
@@ -587,7 +533,7 @@ def test_static_close_to_floor_on_skewed_stream(rng):
     p = np.asarray([0.6, 0.2, 0.1, 0.05, 0.02, 0.01, 0.01, 0.01])
     codes = rng.choice(8, size=(1, 64, 64, 4), p=p).astype(np.uint32)
     qp = _qp(4, 3, rng)
-    enc = wire.encode(codes, qp, backend="rans")
+    enc = wire.encode(codes, qp)
     floor = wire.empirical_entropy_bits(codes, 3)
     assert 8 * len(enc.payload) <= 1.10 * floor
 

@@ -35,7 +35,6 @@ def plan_for():
     cache = {}
 
     def get(op):
-        op = op.resolve()
         if op not in cache:
             cache[op] = pcompile(op, spec)
         return cache[op]
@@ -73,12 +72,16 @@ def test_i_frame_payload_is_the_stateless_container(plan_for):
     assert frame.payload == plan_for(OP).encode(z).data
 
 
-def test_p_chain_reconstructs_codes_exactly(plan_for):
+@pytest.mark.parametrize("bits", range(2, 9))
+def test_p_chain_reconstructs_codes_exactly(plan_for, bits):
     """Temporal prediction is lossless on top of quantization: every frame
     of a long P-chain decodes to the exact codes the encoder quantized —
-    zero drift at any chain length."""
-    enc, dec = _pair(plan_for)
-    plan = plan_for(OP)
+    zero drift at any chain length, at every bit depth (the mod-2^bits
+    delta rides the rANS coder)."""
+    op = OperatingPoint(c=8, bits=bits)
+    cfg = SessionConfig(session_id=1, levels=(op,))
+    enc, dec = SessionEncoder(cfg, plan_for), SessionDecoder(cfg, plan_for)
+    plan = plan_for(op)
     for i, z in enumerate(_z_stream(12)):
         blob, meta = enc.encode(z)
         assert meta.intra == (i == 0)

@@ -22,7 +22,6 @@ def plan_for():
     cache = {}
 
     def get(op):
-        op = op.resolve()
         if op not in cache:
             cache[op] = pcompile(op, spec)
         return cache[op]

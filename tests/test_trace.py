@@ -132,9 +132,7 @@ def test_multi_tenant_trace_deterministic_and_reconciles(tiny_system):
     sheds = [i for i in tr1.instants if i.name == "admission.shed"]
     assert len(sheds) == len(tel1.shed)
     # wall-clock stage timers landed in metrics, never in the trace
-    assert m1.get("stage_seconds", stage="pipeline.encode",
-                  backend="raw") is not None or any(
-        n == "stage_seconds" for n, _, _ in m1.collect())
+    assert m1.get("stage_seconds", stage="pipeline.encode") is not None
 
 
 def test_tracing_does_not_perturb_virtual_clock(tiny_system):
