@@ -14,14 +14,13 @@ repeating the pooled table, so the decoder never special-cases it.
 """
 from __future__ import annotations
 
-import zlib
-
 import numpy as np
 
 from repro.codec import container as box
 from repro.obs import hooks
 from repro.codec.rans import (MAX_PROB_BITS, RANS_L, CorruptStream,
-                              RansTable, encode_static_rows, normalize_freqs)
+                              cum_freqs, encode_static_rows, normalize_freqs,
+                              normalize_freqs_rows)
 
 MAX_BITS = 12                # slot tables are 2^prob_bits; keep them sane
 STATIC_LANES = 32
@@ -61,16 +60,18 @@ def _as_symbol_matrix(codes: np.ndarray, bits: int) -> np.ndarray:
     return np.ascontiguousarray(mat)
 
 
-def _expected_payload_bits(counts: np.ndarray, tables: list[RansTable],
+def _expected_payload_bits(counts: np.ndarray, freqs: np.ndarray,
                            prob_bits: int) -> float:
-    """Cross-entropy estimate of the coded size of each chunk under its
-    table: sum_s counts[s] * (prob_bits - log2(freq[s])). rANS realizes
-    this within ~1%, which is plenty to pick a table layout without coding."""
-    total = 0.0
-    for i, t in enumerate(tables):
-        f = t.freqs.astype(np.float64)
-        total += float(np.sum(counts[i] * (prob_bits - np.log2(f))))
-    return total
+    """Cross-entropy estimate of the coded size of every chunk under its
+    table: sum_s counts[m, s] * (prob_bits - log2(freqs[m, s])), freqs (C, S)
+    or one (1, S) table for all chunks. rANS realizes this within ~1%, which
+    is plenty to pick a table layout without coding. Each row is summed
+    alone and the row sums added in row order, one after another, so the
+    total (and the layout it picks) does not depend on how rows are
+    batched."""
+    bits = np.ascontiguousarray(
+        counts * (prob_bits - np.log2(freqs.astype(np.float64))))
+    return float(np.cumsum(bits.sum(axis=1))[-1])
 
 
 def encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
@@ -107,37 +108,36 @@ def _encode_static_tensor(codes: np.ndarray, bits: int) -> bytes:
         return box.pack_container(bits=bits, prob_bits=prob_bits,
                                   lanes=lanes, tables=tables, chunks=chunks)
 
-    def build(tables: list[RansTable]):
-        # every chunk of the container in one interleaved loop
-        hooks.observe("codec_encode_rows", n_ch)
-        states, words = encode_static_rows(mat, tables, lanes)
-        chunks = [(k, states[i], words[i]) for i in range(n_ch)]
-        return box.pack_container(bits=bits, prob_bits=prob_bits,
-                                  lanes=lanes,
-                                  tables=[t.freqs for t in tables],
-                                  chunks=chunks)
-
-    per_channel = [RansTable.from_counts(counts[i], prob_bits)
-                   for i in range(n_ch)]
-    tables = per_channel
+    # the table stage: all C tables in one pass, each candidate table
+    # section compressed once, the chosen one reused on the wire
+    freqs = normalize_freqs_rows(counts, prob_bits)
+    section = box.table_section(freqs)
     if n_ch > 1:
         # pick the table layout BEFORE coding: compare the cross-entropy
-        # payload estimate plus the zlib'd table blob each layout transmits
+        # payload estimate plus the table section each layout transmits
         # (small tiles cannot amortize C tables), then code once
-        pooled = RansTable.from_counts(counts.sum(axis=0), prob_bits)
-        pooled_tables = [pooled] * n_ch
-
-        def table_blob_bits(ts):
-            raw = np.concatenate([t.freqs.astype("<u2") for t in ts])
-            return 8 * len(zlib.compress(raw.tobytes(), 9))
-
-        cost_per = (_expected_payload_bits(counts, per_channel, prob_bits)
-                    + table_blob_bits(per_channel))
-        cost_pool = (_expected_payload_bits(counts, pooled_tables, prob_bits)
-                     + table_blob_bits(pooled_tables))
+        pooled = normalize_freqs_rows(counts.sum(axis=0, keepdims=True),
+                                      prob_bits)
+        pooled_rows = np.repeat(pooled, n_ch, axis=0)
+        pooled_section = box.table_section(pooled_rows)
+        cost_per = (_expected_payload_bits(counts, freqs, prob_bits)
+                    + 8 * len(section))
+        cost_pool = (_expected_payload_bits(counts, pooled, prob_bits)
+                     + 8 * len(pooled_section))
+        layout = "per_channel"
         if cost_pool < cost_per:
-            tables = pooled_tables
-    return build(tables)
+            freqs, section = pooled_rows, pooled_section
+            layout = "pooled"
+        hooks.count("codec_table_layout", layout=layout)
+
+    # every chunk of the container in one interleaved loop
+    hooks.observe("codec_encode_rows", n_ch)
+    states, words = encode_static_rows(mat, freqs, cum_freqs(freqs),
+                                       prob_bits, lanes)
+    return box.pack_container(bits=bits, prob_bits=prob_bits, lanes=lanes,
+                              section=section,
+                              chunks=[(k, states[i], words[i])
+                                      for i in range(n_ch)])
 
 
 def decode_tensor(payload: bytes, shape: tuple, bits: int) -> np.ndarray:

@@ -52,23 +52,35 @@ class ContainerHeader:
     n_chunks: int
 
 
+def table_section(tables) -> bytes:
+    """The container's table section: zlib (level 9) of the tables'
+    ``<u2`` frequencies, chunk after chunk. ``tables`` is an (M, S) array
+    or a list of (S,) arrays; no tables give an empty section."""
+    if len(tables) == 0:
+        return b""
+    raw = np.concatenate(tables, axis=None).astype("<u2")
+    return zlib.compress(raw.tobytes(), 9)
+
+
 def pack_container(*, bits: int, prob_bits: int, lanes: int,
-                   tables: list[np.ndarray],
-                   chunks: list[tuple[int, np.ndarray, bytes]]) -> bytes:
+                   chunks: list[tuple[int, np.ndarray, bytes]],
+                   tables: list[np.ndarray] | None = None,
+                   section: bytes | None = None) -> bytes:
     """Assemble the wire blob.
 
-    tables : per-chunk frequency arrays
-    chunks : [(symbol count, lane states (lanes,) uint32, word bytes)]
+    chunks  : [(symbol count, lane states (lanes,) uint32, word bytes)]
+    tables  : per-chunk frequency arrays, compressed here; or
+    section : the :func:`table_section` of those tables, already compressed
     """
-    if len(tables) != len(chunks):
-        raise ValueError(f"{len(tables)} tables for {len(chunks)} chunks")
-    table_blob = b""
-    if tables:
-        raw = np.concatenate([t.astype("<u2") for t in tables]).tobytes()
-        table_blob = zlib.compress(raw, 9)
+    if (tables is None) == (section is None):
+        raise ValueError("pass exactly one of tables and section")
+    if tables is not None:
+        if len(tables) != len(chunks):
+            raise ValueError(f"{len(tables)} tables for {len(chunks)} chunks")
+        section = table_section(tables)
     hdr = _HEADER.pack(MAGIC, VERSION, MODE_STATIC, bits, prob_bits, lanes,
-                       0, len(chunks), len(table_blob))
-    out = [hdr, _HEADER_CRC.pack(zlib.crc32(hdr)), table_blob]
+                       0, len(chunks), len(section))
+    out = [hdr, _HEADER_CRC.pack(zlib.crc32(hdr)), section]
     for count, states, words in chunks:
         if len(words) % 2:
             raise ValueError("word stream must be whole 16-bit words")

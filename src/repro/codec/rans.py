@@ -49,51 +49,67 @@ class CorruptStream(ValueError):
     """A bitstream failed structural or arithmetic validation."""
 
 
-def normalize_freqs(counts: np.ndarray, prob_bits: int) -> np.ndarray:
-    """Scale histogram ``counts`` to sum exactly to ``1 << prob_bits``.
+def normalize_freqs_rows(counts: np.ndarray, prob_bits: int) -> np.ndarray:
+    """Scale each row of histogram ``counts`` (M, S) to sum exactly to
+    ``1 << prob_bits`` -> (M, S) uint32.
 
     Every symbol of the alphabet gets frequency >= 1 (even zero-count ones),
-    so the resulting table can code *any* symbol — required for lane
-    padding. Deterministic:
-    ties break by symbol index, so encoder and decoder derive identical
-    tables from identical counts.
+    so each table can code *any* symbol — required for lane padding. An
+    all-zero row scales as if every count were 1. Deterministic: ties break
+    by symbol index, so encoder and decoder derive identical tables from
+    identical counts. Row m is exactly what normalizing it alone gives; all
+    rows go through one set of array operations.
     """
     if not 1 <= prob_bits <= MAX_PROB_BITS:
         raise ValueError(f"prob_bits must be in [1, {MAX_PROB_BITS}], "
                          f"got {prob_bits}")
     c = np.maximum(np.asarray(counts, dtype=np.int64), 0)
-    n = c.size
+    if c.ndim != 2:
+        raise ValueError(f"counts must be (M, S), got shape {c.shape}")
+    n = c.shape[1]
     target = 1 << prob_bits
     if n == 0:
         raise ValueError("empty alphabet")
     if n > target:
         raise ValueError(f"alphabet of {n} symbols does not fit "
                          f"prob_bits={prob_bits}")
-    total = int(c.sum())
-    if total == 0:
-        c = np.ones(n, dtype=np.int64)
-        total = n
+    total = c.sum(axis=1, keepdims=True)
+    c = np.where(total == 0, 1, c)
+    total = np.where(total == 0, n, total)
     scaled = (c * target) // total
     freqs = np.maximum(scaled, 1)
-    diff = target - int(freqs.sum())
-    if diff > 0:
-        # hand the shortfall to the largest fractional remainders
-        rem = c * target - scaled * total
-        order = np.lexsort((np.arange(n), -rem))
-        freqs[order[:diff]] += 1
-    elif diff < 0:
-        # the min-1 bumps oversubscribed the budget; reclaim from the
-        # largest frequencies (they lose the least precision)
-        order = np.argsort(-freqs, kind="stable")
-        need = -diff
-        for i in order:
-            take = min(int(freqs[i]) - 1, need)
-            freqs[i] -= take
-            need -= take
-            if need == 0:
-                break
-        assert need == 0, "cannot normalize: alphabet too large"
+    diff = target - freqs.sum(axis=1, keepdims=True)
+    # a shortfall goes to the largest fractional remainders, one each; the
+    # ranks sort remainders down, ties by symbol index
+    rem = c * target - scaled * total
+    rank = np.empty_like(freqs)
+    np.put_along_axis(rank, np.argsort(-rem, axis=1, kind="stable"),
+                      np.broadcast_to(np.arange(n), freqs.shape), axis=1)
+    freqs += rank < diff
+    # the min-1 bumps oversubscribed the budget: reclaim it from the largest
+    # frequencies first (they lose the least precision), each down to 1,
+    # until the excess is paid
+    need = np.maximum(-diff, 0)
+    order = np.argsort(-freqs, axis=1, kind="stable")
+    spare = np.take_along_axis(freqs, order, axis=1) - 1
+    before = np.cumsum(spare, axis=1) - spare
+    take = np.clip(need - before, 0, spare)
+    assert np.array_equal(take.sum(axis=1, keepdims=True), need), \
+        "cannot normalize: alphabet too large"
+    np.put_along_axis(freqs, order,
+                      np.take_along_axis(freqs, order, axis=1) - take, axis=1)
     return freqs.astype(np.uint32)
+
+
+def normalize_freqs(counts: np.ndarray, prob_bits: int) -> np.ndarray:
+    """Scale one histogram: the M = 1 case of :func:`normalize_freqs_rows`."""
+    return normalize_freqs_rows(np.reshape(counts, (1, -1)), prob_bits)[0]
+
+
+def cum_freqs(freqs: np.ndarray) -> np.ndarray:
+    """Exclusive prefix sums of frequency tables along the last axis."""
+    return (np.cumsum(freqs, axis=-1, dtype=np.uint64)
+            - freqs).astype(np.uint32)
 
 
 @dataclass
@@ -112,8 +128,7 @@ class RansTable:
                 f"expected {1 << self.prob_bits}")
         if self.freqs.size and int(self.freqs.min()) < 1:
             raise CorruptStream("frequency table has zero-frequency symbols")
-        self.cum = (np.cumsum(self.freqs, dtype=np.uint64)
-                    - self.freqs).astype(np.uint32)
+        self.cum = cum_freqs(self.freqs)
 
     @classmethod
     def from_counts(cls, counts, prob_bits: int) -> "RansTable":
@@ -235,19 +250,19 @@ def rans_decode(states: np.ndarray, words: bytes, count: int,
     return out.reshape(-1)[:count]
 
 
-def encode_static_rows(symbols: np.ndarray, tables: list[RansTable],
+def encode_static_rows(symbols: np.ndarray, freqs: np.ndarray,
+                       cums: np.ndarray, prob_bits: int,
                        lanes: int) -> tuple[np.ndarray, list[bytes]]:
     """Static-table coder for M streams of one length: pad, gather (f, c),
     and code all rows in one :func:`rans_encode_rows` loop.
 
-    symbols: (M, K); row m is coded with ``tables[m]`` (all of one
-    ``prob_bits``). Padding uses each row's most probable symbol (cheapest
-    per pad symbol); the decoder truncates by count, so only the wire cost
-    is affected. Returns ``(lane states (M, lanes), [word bytes of row m])``.
+    symbols: (M, K); row m is coded with the table ``freqs[m]`` and its
+    exclusive prefix sums ``cums[m]``, both (M, S). Padding uses each row's
+    most probable symbol (cheapest per pad symbol); the decoder truncates by
+    count, so only the wire cost is affected. Returns ``(lane states (M,
+    lanes), [word bytes of row m])``.
     """
     symbols = np.asarray(symbols, np.uint32)
-    freqs = np.stack([t.freqs for t in tables])
-    cums = np.stack([t.cum for t in tables])
     pad = (-symbols.shape[1]) % lanes
     if pad:
         fill = np.argmax(freqs, axis=1).astype(np.uint32)
@@ -255,7 +270,7 @@ def encode_static_rows(symbols: np.ndarray, tables: list[RansTable],
             [symbols, np.repeat(fill[:, None], pad, axis=1)], axis=1)
     return rans_encode_rows(np.take_along_axis(freqs, symbols, axis=1),
                             np.take_along_axis(cums, symbols, axis=1),
-                            tables[0].prob_bits, lanes)
+                            prob_bits, lanes)
 
 
 def encode_static(symbols: np.ndarray, table: RansTable,
@@ -263,5 +278,6 @@ def encode_static(symbols: np.ndarray, table: RansTable,
     """One stream under one static table: the M = 1 case of
     :func:`encode_static_rows`."""
     states, words = encode_static_rows(np.reshape(symbols, (1, -1)),
-                                       [table], lanes)
+                                       table.freqs[None], table.cum[None],
+                                       table.prob_bits, lanes)
     return states[0], words[0]

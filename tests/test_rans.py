@@ -5,6 +5,7 @@ Round-trip properties run under hypothesis when installed (via the
 hypothesis_compat shim) and as seeded spot checks otherwise.
 """
 import hashlib
+import zlib
 
 import numpy as np
 import pytest
@@ -14,9 +15,10 @@ from repro.codec import (CorruptStream, RansContainer, RansTable,
                          decode_channels, decode_tensor, encode_static_tensor,
                          normalize_freqs, rans_decode)
 from repro.codec import container as box
+from repro.codec.backend import _expected_payload_bits
 from repro.codec.batch import decode_tensor_batch
 from repro.codec.rans import (RANS_L, encode_static, encode_static_rows,
-                              rans_encode_rows)
+                              normalize_freqs_rows, rans_encode_rows)
 from repro.obs import hooks
 from repro.obs.metrics import MetricsRegistry
 from repro.core import codec as wire
@@ -54,6 +56,87 @@ def test_normalize_freqs_all_zero_counts():
 def test_normalize_freqs_rejects_oversized_alphabet():
     with pytest.raises(ValueError, match="does not fit"):
         normalize_freqs(np.ones(1 << 13), 12)
+
+
+def _scalar_normalize_freqs(counts, prob_bits):
+    """The one-row normalization written out symbol by symbol: scale, bump
+    zeros to 1, then hand a shortfall to the largest remainders (ties by
+    index) or reclaim an excess from the largest frequencies down. Returns
+    (freqs, the branch taken)."""
+    c = [max(int(v), 0) for v in counts]
+    n, target = len(c), 1 << prob_bits
+    total = sum(c)
+    if total == 0:
+        c, total = [1] * n, n
+    scaled = [v * target // total for v in c]
+    freqs = [max(v, 1) for v in scaled]
+    diff = target - sum(freqs)
+    if diff > 0:
+        rem = [c[i] * target - scaled[i] * total for i in range(n)]
+        for i in sorted(range(n), key=lambda i: (-rem[i], i))[:diff]:
+            freqs[i] += 1
+        return np.array(freqs, np.uint32), "shortfall"
+    need = -diff
+    for i in sorted(range(n), key=lambda i: (-freqs[i], i)):
+        take = min(freqs[i] - 1, need)
+        freqs[i] -= take
+        need -= take
+    assert need == 0
+    return np.array(freqs, np.uint32), ("oversubscribed" if diff else "exact")
+
+
+def _norm_counts(kind, m, n, r):
+    """(m, n) histograms that drive normalization down one branch each."""
+    if kind == "shortfall":                 # no zeros: floors fall short
+        return r.integers(1, 1000, size=(m, n))
+    if kind == "oversubscribed":            # real codes: a peak, many zeros
+        counts = np.zeros((m, n), np.int64)
+        for row in counts:
+            hot = r.choice(n, size=max(2, n // 64), replace=False)
+            row[hot] = np.floor(1e5 * 0.6 ** np.arange(hot.size))
+        return counts
+    if kind == "all_zero":
+        return np.zeros((m, n), np.int64)
+    counts = np.zeros((m, n), np.int64)     # "single_symbol"
+    counts[np.arange(m), r.integers(n, size=m)] = r.integers(1, 10**6, m)
+    return counts
+
+
+@pytest.mark.parametrize("kind", ["shortfall", "oversubscribed", "all_zero",
+                                  "single_symbol", "mixed"])
+@pytest.mark.parametrize("prob_bits", [9, 14, 15])
+@pytest.mark.parametrize("m", [1, 8, 128])
+def test_normalize_freqs_rows_matches_each_row_alone(m, prob_bits, kind):
+    r = np.random.default_rng([m, prob_bits, len(kind)])
+    # 4096 symbols at prob_bits 14 scale exactly, so any zero count bumped
+    # to 1 oversubscribes the budget
+    n = 4096 if prob_bits >= 12 else 256
+    kinds = (["shortfall", "oversubscribed", "all_zero", "single_symbol"]
+             if kind == "mixed" else [kind])
+    counts = np.concatenate([_norm_counts(kinds[i % len(kinds)], 1, n, r)
+                             for i in range(m)])
+    rows = normalize_freqs_rows(counts, prob_bits)
+    assert rows.shape == (m, n) and rows.dtype == np.uint32
+    branches = set()
+    for i in range(m):
+        ref, branch = _scalar_normalize_freqs(counts[i], prob_bits)
+        branches.add(branch)
+        assert np.array_equal(rows[i], ref), i
+        assert np.array_equal(normalize_freqs(counts[i], prob_bits), ref), i
+    want = {"shortfall": {"shortfall"}, "oversubscribed": {"oversubscribed"}}
+    assert branches >= want.get(kind, set())
+
+
+@pytest.mark.parametrize("counts,prob_bits,msg", [
+    (np.ones((3, 1 << 13)), 12, "does not fit"),
+    (np.ones((2, 16)), 0, "prob_bits must be in"),
+    (np.ones((2, 16)), 16, "prob_bits must be in"),
+    (np.ones((2, 0)), 12, "empty alphabet"),
+    (np.ones(16), 12, "must be"),
+])
+def test_normalize_freqs_rows_rejects(counts, prob_bits, msg):
+    with pytest.raises(ValueError, match=msg):
+        normalize_freqs_rows(counts, prob_bits)
 
 
 # ---------------------------------------------------------------------------
@@ -262,6 +345,168 @@ def test_static_encode_observes_rows_once(rng, c):
 
 
 # ---------------------------------------------------------------------------
+# the table stage: layout choice and table section against the formula
+# written out row by row
+# ---------------------------------------------------------------------------
+
+def _reference_layout(codes, bits):
+    """Tables, layout, both costs and the counts, as the row-by-row formula
+    picks them: each channel's table normalized alone, the cross-entropy
+    payload summed row by row in row order, and each layout's table blob
+    zlib'd at level 9."""
+    c = codes.shape[-1]
+    flat = codes.reshape(-1, c)
+    counts = np.stack([np.bincount(flat[:, i], minlength=1 << bits)
+                       for i in range(c)])
+    prob_bits = max(14, bits + 2)
+    per = [_scalar_normalize_freqs(row, prob_bits)[0] for row in counts]
+    pooled = [_scalar_normalize_freqs(counts.sum(axis=0), prob_bits)[0]] * c
+
+    def cost(tables):
+        total = 0.0
+        for i, t in enumerate(tables):
+            total += float(np.sum(counts[i] * (prob_bits - np.log2(
+                t.astype(np.float64)))))
+        raw = np.concatenate([t.astype("<u2") for t in tables]).tobytes()
+        return total + 8 * len(zlib.compress(raw, 9))
+
+    cost_per, cost_pool = cost(per), cost(pooled)
+    if c > 1 and cost_pool < cost_per:
+        return pooled, "pooled", cost_per, cost_pool, counts
+    return per, "per_channel", cost_per, cost_pool, counts
+
+
+def _near_tie_codes(seed, h):
+    """(1, h, 64, 8) codes whose channel widths span 2..8: near h = 60 the
+    two layouts cost the same to a few bits in about 150,000."""
+    r = np.random.default_rng([seed, h])
+    z = r.laplace(0.0, 1.0, size=(1, h, 64, 8)) * np.geomspace(2, 8, 8)
+    return np.clip(np.round(128 + z), 0, 255).astype(np.uint32)
+
+
+def _delta_codes(seed):
+    """A P-frame-like residual: (1, 64, 64, 128) codes at the zero point
+    128 but for 2% of them, within +-8 of it."""
+    r = np.random.default_rng(seed)
+    codes = np.full((1, 64, 64, 128), 128, np.uint32)
+    hit = r.random(codes.shape) < 0.02
+    codes[hit] = r.integers(120, 137, size=int(hit.sum()))
+    return codes
+
+
+_LAYOUT_CASES = {
+    # (seed, h) where the winner wins by under 2 bits
+    "tie_pooled_24_64": (lambda: _near_tie_codes(24, 64), "pooled", 2),
+    "tie_pooled_25_59": (lambda: _near_tie_codes(25, 59), "pooled", 2),
+    "tie_per_channel_39_64": (lambda: _near_tie_codes(39, 64),
+                              "per_channel", 2),
+    "tie_per_channel_37_63": (lambda: _near_tie_codes(37, 63),
+                              "per_channel", 2),
+    "delta_c128": (lambda: _delta_codes(5), "pooled", None),
+    "c1": (lambda: _laplace_codes(9, np.full(1, 3.0)), "per_channel", None),
+    **{f"golden_{name}": ((lambda g=g: _laplace_codes(g[0], g[1])),
+                          "pooled" if g[2] else "per_channel", None)
+       for name, g in _GOLDEN.items()},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAYOUT_CASES))
+def test_table_layout_and_container_match_row_formula(case):
+    make, layout, tie = _LAYOUT_CASES[case]
+    codes = make()
+    tables, ref_layout, cost_per, cost_pool, counts = _reference_layout(
+        codes, 8)
+    assert ref_layout == layout
+    if tie is not None:                       # a near-tie, in bits
+        assert abs(cost_pool - cost_per) < tie
+    # the stacked costs are the row formula's floats, bit for bit
+    per = normalize_freqs_rows(counts, 14)
+    pooled = normalize_freqs_rows(counts.sum(axis=0, keepdims=True), 14)
+    assert (_expected_payload_bits(counts, per, 14)
+            + 8 * len(box.table_section(per))) == cost_per
+    assert (_expected_payload_bits(counts, pooled, 14) + 8 * len(
+        box.table_section(np.repeat(pooled, len(per), axis=0)))) == cost_pool
+    m = MetricsRegistry()
+    with hooks.active(m):
+        blob = encode_static_tensor(codes, 8)
+    cont = RansContainer.parse(blob)
+    got = [cont.chunk_table(j) for j in range(cont.header.n_chunks)]
+    assert all(np.array_equal(g, t) for g, t in zip(got, tables))
+    n_ch = codes.shape[-1]
+    assert (m.get("codec_table_layout", layout=layout) is not None) == \
+        (n_ch > 1)
+    # the whole container, rebuilt from the formula's tables with each row
+    # coded alone
+    syms = codes.reshape(-1, n_ch).T
+    lanes = cont.header.lanes
+    chunks = []
+    for i in range(n_ch):
+        table = RansTable(freqs=tables[i], prob_bits=cont.header.prob_bits)
+        states, words = encode_static(syms[i], table, lanes)
+        chunks.append((syms.shape[1], states, words))
+    assert blob == box.pack_container(
+        bits=8, prob_bits=cont.header.prob_bits, lanes=lanes,
+        tables=tables, chunks=chunks)
+
+
+@pytest.mark.parametrize("c,pooled", [(1, False), (8, False), (8, True),
+                                      (128, False), (128, True)])
+def test_table_section_compressed_once_per_candidate(monkeypatch, c, pooled):
+    scales = np.full(c, 6.0) if pooled else np.geomspace(0.5, 40, c)
+    codes = _laplace_codes(1400 + c, scales)
+    want = encode_static_tensor(codes, 8)
+    per, layout, _, _, _ = _reference_layout(codes, 8)
+    assert (layout == "pooled") == pooled
+    per_raw = np.concatenate([t.astype("<u2") for t in per]).tobytes()
+    seen = []
+    real = zlib.compress
+
+    def spy(data, *args):
+        seen.append(bytes(data))
+        return real(data, *args)
+
+    monkeypatch.setattr(zlib, "compress", spy)
+    assert encode_static_tensor(codes, 8) == want
+    # the per-channel section once, and the pooled one once beside it when
+    # there is a choice to make; never the chosen one again
+    assert seen.count(per_raw) == 1
+    assert len(seen) == (1 if c == 1 else 2)
+
+
+@pytest.mark.parametrize("given", ["tables", "section", "both", "neither"])
+def test_pack_container_takes_tables_or_their_section(given):
+    tables = [normalize_freqs(np.arange(1, 17), 12)] * 2
+    chunks = [(0, np.full(1, RANS_L, "<u4"), b"")] * 2
+    args = {"tables": tables, "section": box.table_section(tables)}
+    if given in args:
+        want = box.pack_container(bits=4, prob_bits=12, lanes=1,
+                                  tables=tables, chunks=chunks)
+        assert box.pack_container(bits=4, prob_bits=12, lanes=1,
+                                  chunks=chunks,
+                                  **{given: args[given]}) == want
+        return
+    with pytest.raises(ValueError, match="exactly one"):
+        box.pack_container(bits=4, prob_bits=12, lanes=1, chunks=chunks,
+                           **(args if given == "both" else {}))
+
+
+@pytest.mark.parametrize("c", [1, 8, 128])
+def test_table_layout_counter_once_per_container(rng, c):
+    codes = rng.integers(0, 256, size=(1, 16, 16, c)).astype(np.uint32)
+    m = MetricsRegistry()
+    with hooks.active(m):
+        for _ in range(3):
+            encode_static_tensor(codes, 8)
+    # 256 symbols a channel cannot amortize a table each: pooled wins
+    assert m.get("codec_table_layout", layout="per_channel") is None
+    pooled = m.get("codec_table_layout", layout="pooled")
+    assert (pooled.value if c > 1 else pooled) == (3 if c > 1 else None)
+    encode_static_tensor(codes, 8)             # nothing installed
+    assert not hooks.enabled()
+    assert c == 1 or pooled.value == 3
+
+
+# ---------------------------------------------------------------------------
 # batched decode: per-row slot tables against the scalar decoder
 # ---------------------------------------------------------------------------
 
@@ -281,6 +526,13 @@ def _batch_table(kind, bits, prob_bits, r):
     return RansTable.from_counts(counts, prob_bits)
 
 
+def _encode_rows(syms, tables, lanes):
+    """Code row m of ``syms`` under ``tables[m]`` in one stacked loop."""
+    return encode_static_rows(syms, np.stack([t.freqs for t in tables]),
+                              np.stack([t.cum for t in tables]),
+                              tables[0].prob_bits, lanes)
+
+
 def _batch_container(tables, k, lanes, bits, r):
     """Symbols drawn from each chunk's table, with some of the rarest mixed
     in, coded into one static container -> (blob, symbols (C, k))."""
@@ -291,7 +543,7 @@ def _batch_container(tables, k, lanes, bits, r):
         rare = np.flatnonzero(t.freqs == t.freqs.min())
         pos = r.choice(k, size=max(1, k // 64), replace=False)
         row[pos] = r.choice(rare, size=pos.size)
-    states, words = encode_static_rows(syms, tables, lanes)
+    states, words = _encode_rows(syms, tables, lanes)
     blob = box.pack_container(
         bits=bits, prob_bits=tables[0].prob_bits,
         lanes=lanes, tables=[t.freqs for t in tables],
@@ -345,7 +597,7 @@ def _corrupt_static(kind, r):
     blobs = [_batch_container(tables, k, lanes, bits, r)[0]
              for _ in range(3)]
     syms = r.integers(0, 1 << bits, size=(2, k)).astype(np.uint32)
-    states, words = encode_static_rows(syms, tables, lanes)
+    states, words = _encode_rows(syms, tables, lanes)
     states, words = states.copy(), list(words)
     freqs = [t.freqs.copy() for t in tables]
     if kind == "table_sum":
